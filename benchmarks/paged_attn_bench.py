@@ -32,6 +32,7 @@ import numpy as np
 
 from benchmarks.common import bench_record, emit
 from repro.core.hardware import TPU_V5E
+from repro.core.plan import int8_scale_lanes
 from repro.kernels.paged_attention.ops import paged_attention as fused_attn
 from repro.models.cache import paged_gather
 from repro.models.layers import paged_attention as gather_attn
@@ -47,7 +48,10 @@ def _build(B, ctx, bs, kv_dtype, H=4, KH=2, D=64, seed=0):
     if kv_dtype == "int8":
         def q8(x):
             s = jnp.maximum(jnp.abs(x).max(-1, keepdims=True), 1e-12) / 127.0
-            return jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8), s
+            q = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8)
+            rows = s.reshape(N, 1, bs * KH)  # pool layout (N, 1, P)
+            pad = int8_scale_lanes(KH, bs) - bs * KH
+            return q, jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
 
         qk, sk = q8(k)
         qv, sv = q8(v)

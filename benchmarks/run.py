@@ -1,8 +1,6 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
+# The first benchmark that fails stops the run with its traceback.
 from __future__ import annotations
-
-import sys
-import traceback
 
 
 def main() -> None:
@@ -22,7 +20,6 @@ def main() -> None:
     )
 
     print("name,us_per_call,derived")
-    ok = True
     for mod in (
         table2_parallel_modes,
         table5_utilization,
@@ -37,13 +34,7 @@ def main() -> None:
         family_search,
         obs_bench,
     ):
-        try:
-            mod.run()
-        except Exception:
-            ok = False
-            traceback.print_exc()
-    if not ok:
-        sys.exit(1)
+        mod.run()
 
 
 if __name__ == "__main__":

@@ -101,7 +101,7 @@ def grad_exchange_report(archs=("bert-base", "vit-base"), out_path="BENCH_dist.j
     grads = jax.tree.map(
         lambda p: jax.random.normal(jax.random.PRNGKey(1), p.shape) * 1e-2, params
     )
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh(
@@ -109,7 +109,7 @@ def grad_exchange_report(archs=("bert-base", "vit-base"), out_path="BENCH_dist.j
     )
     exchanged = shard_map(
         lambda g: jax.tree.map(lambda x: compressed_psum(x, "data", "int8"), g),
-        mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
     )(grads)
     errs = [
         float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-12))

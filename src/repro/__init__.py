@@ -1,9 +1,1 @@
-"""CAT reproduction: customized transformer accelerator framework in JAX.
-
-Importing ``repro`` applies the pinned-toolchain jax compat shims so every
-entry point (launchers, tests, subprocess snippets) sees the same jax
-surface regardless of the installed 0.4.x/0.5.x version.
-"""
-from repro._jax_compat import ensure_jax_compat
-
-ensure_jax_compat()
+"""CAT reproduction: customized transformer accelerator framework in JAX."""

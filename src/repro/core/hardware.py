@@ -21,6 +21,8 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class HardwareSpec:
@@ -188,6 +190,32 @@ def get_hardware(name: str) -> HardwareSpec:
             f"unknown hardware {name!r}; registered: {sorted(_REGISTRY)}"
         )
     return _REGISTRY[name]
+
+
+# ``device_kind`` as JAX reports it -> the registered spec of that chip.
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+}
+
+
+def hardware_for_kind(device_kind: str) -> HardwareSpec:
+    """The spec of a chip by its ``device_kind``; an unknown kind raises
+    rather than planning against some other chip's numbers."""
+    if device_kind not in DEVICE_KINDS:
+        raise KeyError(
+            f"no HardwareSpec for device kind {device_kind!r}; "
+            f"known kinds: {sorted(DEVICE_KINDS)}"
+        )
+    return get_hardware(DEVICE_KINDS[device_kind])
+
+
+def resolve_hardware(planning_target: str) -> HardwareSpec:
+    """The spec the planner sizes against.  On a TPU backend the chip the
+    program runs on decides; elsewhere (CPU runs, dry-runs) the named
+    registered device is the planning target."""
+    if jax.default_backend() == "tpu":
+        return hardware_for_kind(jax.devices()[0].device_kind)
+    return get_hardware(planning_target)
 
 
 def registered_hardware() -> tuple[str, ...]:

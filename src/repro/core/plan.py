@@ -24,6 +24,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Optional
 
+import numpy as np
+
 from repro.core.hardware import DEFAULT_HARDWARE, VCK5000, HardwareSpec
 from repro.core.pu import MMTileSpec, derive_pu_family, pick_pu
 
@@ -570,6 +572,23 @@ def _pow2_floor(n: int) -> int:
     return p
 
 
+_DTYPE_BYTES = {"fp32": 4, "bf16": 2, "int8": 1}
+# The dtype the server holds its weights (and so its activations) in.  The
+# KV pool's HBM budget and the kernel's VMEM footprint are sized against
+# it, and the serving launchers initialize weights in it.
+SERVE_WEIGHT_DTYPE = "float32"
+# HBM XLA:TPU keeps from a program: it compiles against 15.75 of a v5e's
+# 16 GiB.
+_XLA_HBM_RESERVE = 2**28
+
+
+def int8_scale_lanes(n_kv_heads: int, block_size: int) -> int:
+    """Lanes of one page's int8 scale row: ``block_size * n_kv_heads`` fp32
+    scales (token-major), rounded up to whole 128-lane vregs so the fused
+    kernel can fetch a page's scales with one aligned DMA."""
+    return -(-block_size * n_kv_heads // 128) * 128
+
+
 def largest_divisor_of(n: int, cap: int) -> int:
     """Largest divisor of ``n`` that is <= ``cap`` (>= 1).  Unlike
     :func:`_largest_divisor_leq` it puts no divisibility demand on ``cap``."""
@@ -631,10 +650,12 @@ def derive_serve_plan(
       defaults to ``prefill_chunk`` (prefill keeps its compute-bound chunk,
       decode slots carry the dead rows — the explicit latency/throughput
       trade, overridable).
-    * **pages per VMEM tile** — the fused kernel double-buffers k+v page
-      tiles in VMEM; the tile height is the largest block-table divisor
-      whose tiles fit an eighth of the chip's VMEM (the rest holds q, the
-      accumulator and the output block).
+    * **pages per VMEM tile** — the largest block-table divisor at which
+      the fused kernel's whole footprint for one grid step (q and output
+      blocks, the softmax carry, the score arrays and the page tile with
+      its in-kernel copies: ``kernels/paged_attention/kernel.vmem_bytes``)
+      fits an eighth of the chip's VMEM, Mosaic's default scoped limit
+      (16 of 128 MiB on v5e).
     * **rolled decode steps (K cap)** — how many decode iterations one host
       dispatch should carry.  A dispatch costs ``hw.dispatch_overhead_s``
       no matter how much it launches, while one decode step is
@@ -663,6 +684,10 @@ def derive_serve_plan(
       (``Request.slo_ttft_ms``) additionally drive runtime chunk sizing in
       the scheduler against *measured* step times.
 
+    The KV pool gets the HBM the weights leave free at
+    ``SERVE_WEIGHT_DTYPE``, the width the server holds them in.  (The decode
+    roofline above still prices a bf16 weight stream.)
+
     ``oversubscribe`` scales the block pool relative to the worst case
     (every slot at ``max_seq_len``).  At the default 1.0 the pool can host
     every admitted request to full context, so derived plans are
@@ -677,41 +702,10 @@ def derive_serve_plan(
     n_attn = sum(
         1 for i in range(cfg.n_layers) if cfg.layer_kind(i) in ("attn", "swa", "local")
     )
-    weight_bytes = cfg.param_count() * 2.0 / max(ma, 1)
-    kv_budget = int(max(hw.hbm_bytes - weight_bytes, 0.1 * hw.hbm_bytes))
-
-    def per_token(dtype: str) -> int:
-        b = {"fp32": 4, "bf16": 2, "int8": 1}[dtype]
-        tok = n_attn * 2 * cfg.n_kv_heads * cfg.d_head * b
-        if dtype == "int8":  # per-(token, head) fp32 scale rides along
-            tok += n_attn * 2 * cfg.n_kv_heads * 4
-        return tok
-
-    # Roofline batch: tokens per step needed to amortize the weight stream.
-    # A degenerate device with no off-chip bandwidth reports an infinite
-    # machine balance (nothing amortizes); clamp so the int() below is total
-    # — the KV-capacity cap then decides the batch alone.
-    balance = min(hw.machine_balance_bf16, 2.0**20)
-    ridge = max(1, int(balance * 2.0 / (2.0 * max(ma, 1))))
-    if kv_dtype is None:
-        want = decode_batch or _pow2_floor(ridge)
-        fits_bf16 = want * max_seq_len * per_token("bf16") <= kv_budget
-        kv_dtype = "bf16" if fits_bf16 else "int8"
-    kv_tok = per_token(kv_dtype)
-    cap = max(1, kv_budget // max(max_seq_len * kv_tok, 1))
-    if not fused_attention:
-        # Gather-bytes term (fallback only): every slot drags a dense
-        # write+read of its full-context cache through HBM each step.
-        gather_tax = 2.0 * max_seq_len * kv_tok
-        cap = max(1, min(cap, int(weight_bytes / max(gather_tax, 1.0))))
-    if decode_batch is None:
-        decode_batch = max(1, min(_pow2_floor(ridge), _pow2_floor(cap)))
+    weight_bytes = cfg.param_count() * 2.0 / max(ma, 1)  # bf16 roofline
     if block_size is None:
         block_size = max(8, hw.mxu_dim // 8)
     block_size = min(block_size, max_seq_len)
-    max_blocks_per_seq = -(-max_seq_len // block_size)  # ceil
-    pool = max(max_blocks_per_seq, int(decode_batch * max_blocks_per_seq * oversubscribe))
-    n_blocks = 1 + pool + slack_blocks  # +1: block 0 is trash
     if prefill_chunk is None:
         prefill_chunk = min(max_seq_len, max(block_size, 256))
     if mixed_slab_width is None:
@@ -727,15 +721,74 @@ def derive_serve_plan(
         need = -(-int(typical_prompt_len or max_seq_len) // steps_budget)
         mixed_slab_width = max(int(mixed_slab_width), need)
     mixed_slab_width = max(1, min(mixed_slab_width, max_seq_len))
+
+    # HBM left for the KV pool and the step's activations: what XLA:TPU
+    # leaves a program, less the weights as served — plus, for fp32
+    # weights, the bf16 copies XLA:TPU feeds the MXU (default-precision
+    # f32 matmuls run on bf16 operands; the converts are hoisted out of the
+    # layer loop, 2 B/param).  Both were read off ``memory_analysis()`` of
+    # the unified step compiled at qwen3-1.7b width for a v5e.
+    act_bytes = np.dtype(SERVE_WEIGHT_DTYPE).itemsize
+    wb = act_bytes + (2 if SERVE_WEIGHT_DTYPE == "float32" else 0)
+    held_weight_bytes = cfg.param_count() * wb / max(ma, 1)
+    usable = hw.hbm_bytes - _XLA_HBM_RESERVE
+    kv_budget = int(max(usable - held_weight_bytes, 0.1 * hw.hbm_bytes))
+    # one slab row's live activations (FFN, fused QKV and residual widths;
+    # ~87 KB measured for qwen3-1.7b in fp32 against 106 KB here)
+    row_bytes = act_bytes * (
+        3 * cfg.effective_ff_width()
+        + (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.d_head
+        + 2 * cfg.d_model
+    )
+
+    def per_token(dtype: str) -> int:
+        tok = n_attn * 2 * cfg.n_kv_heads * cfg.d_head * _DTYPE_BYTES[dtype]
+        if dtype == "int8":  # fp32 scale rows, padded to whole vregs
+            tok += n_attn * 2 * 4 * int8_scale_lanes(cfg.n_kv_heads, block_size) // block_size
+        return tok
+
+    def slot_bytes(dtype: str) -> int:  # a slot's pages at full context + its slab
+        return max_seq_len * per_token(dtype) + mixed_slab_width * row_bytes
+
+    # Roofline batch: tokens per step needed to amortize the weight stream.
+    # A degenerate device with no off-chip bandwidth reports an infinite
+    # machine balance (nothing amortizes); clamp so the int() below is total
+    # — the KV-capacity cap then decides the batch alone.
+    balance = min(hw.machine_balance_bf16, 2.0**20)
+    ridge = max(1, int(balance * 2.0 / (2.0 * max(ma, 1))))
+    if kv_dtype is None:
+        want = decode_batch or _pow2_floor(ridge)
+        kv_dtype = "bf16" if want * slot_bytes("bf16") <= kv_budget else "int8"
+    kv_tok = per_token(kv_dtype)
+    cap = max(1, kv_budget // max(slot_bytes(kv_dtype), 1))
+    if not fused_attention:
+        # Gather-bytes term (fallback only): every slot drags a dense
+        # write+read of its full-context cache through HBM each step.
+        gather_tax = 2.0 * max_seq_len * kv_tok
+        cap = max(1, min(cap, int(weight_bytes / max(gather_tax, 1.0))))
+    if decode_batch is None:
+        decode_batch = max(1, min(_pow2_floor(ridge), _pow2_floor(cap)))
+    max_blocks_per_seq = -(-max_seq_len // block_size)  # ceil
+    pool = max(max_blocks_per_seq, int(decode_batch * max_blocks_per_seq * oversubscribe))
+    n_blocks = 1 + pool + slack_blocks  # +1: block 0 is trash
     if pages_per_tile is None:
-        # one pool page in VMEM: (block_size, n_kv_heads, d_head) values
-        # (+ a (block_size, n_kv_heads, 1) fp32 scale for int8 pages)
-        page_bytes = block_size * cfg.n_kv_heads * (
-            cfg.d_head * {"fp32": 4, "bf16": 2, "int8": 1}[kv_dtype]
-            + (4 if kv_dtype == "int8" else 0)
-        )
-        tile_cap = max(1, (hw.vmem_bytes // 8) // max(2 * page_bytes, 1))
-        pages_per_tile = largest_divisor_of(max_blocks_per_seq, tile_cap)
+        from repro.kernels.paged_attention.kernel import vmem_bytes
+
+        def footprint(ppt: int) -> int:
+            return vmem_bytes(
+                kv_dtype=kv_dtype,
+                q_itemsize=act_bytes,
+                kv_heads=cfg.n_kv_heads,
+                rows=(cfg.n_heads // cfg.n_kv_heads) * int(mixed_slab_width),
+                head_dim=cfg.d_head,
+                block_size=block_size,
+                pages_per_tile=ppt,
+            )
+
+        pages_per_tile = 1
+        for ppt in range(1, max_blocks_per_seq + 1):
+            if max_blocks_per_seq % ppt == 0 and footprint(ppt) <= hw.vmem_bytes // 8:
+                pages_per_tile = ppt
     if rolled_steps is None:
         # Dispatch-overhead slack: one decode step streams the weights once
         # (est_step_s); the host round-trip costs dispatch_overhead_s on

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.train.compression import quantize
@@ -94,7 +94,7 @@ def overlap_all_gather_matmul(mesh, x, w, axis: str = "model"):
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, w)
 
 

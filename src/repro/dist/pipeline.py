@@ -35,7 +35,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -108,7 +108,7 @@ def pipeline_forward(stage_fn, mesh, axis: str = "pod", batch_axes: tuple = ()):
             mesh=mesh,
             in_specs=(w_specs, micro_spec),
             out_specs=micro_spec,
-            check_rep=False,
+            check_vma=False,
         )(w, micro)
 
     return pipelined

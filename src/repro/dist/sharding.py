@@ -225,8 +225,10 @@ class Shardings:
         nd = len(shape)
         spec: list = [None] * nd
         if "paged" in names:
-            if name in ("k", "v", "k_scale", "v_scale") and nd >= 4:
-                spec[-2] = "model"  # (..., n_blocks, block, KV, Dh/1)
+            if name in ("k", "v") and nd >= 4:
+                spec[-2] = "model"  # (..., n_blocks, block, KV, Dh)
+            # int8 scale rows (..., n_blocks, 1, P) interleave every head
+            # on the lanes: they stay replicated
             return self._fit(P(*spec), shape)
         if "cross_kv" in names and nd >= 4:
             spec[-4] = "data"  # encoder memory kv: batch only

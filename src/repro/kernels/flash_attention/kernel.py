@@ -18,13 +18,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro import kernels
 
 NEG_INF = -1e30
 
@@ -91,7 +87,6 @@ def flash_attention_call(
     window: int = 0,
     prefix: int = 0,
     softmax_scale=None,
-    interpret: bool = True,
 ):
     """q: (BH, Sq, D); k/v: (BKH, Sk, D), BH = BKH * n_q_per_kv (per batch).
 
@@ -121,9 +116,9 @@ def flash_attention_call(
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q,), jnp.float32),
-            _VMEM((block_q,), jnp.float32),
-            _VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(q, k, v)

@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_call
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "prefix", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "window", "prefix", "block_q", "block_k"),
 )
 def flash_attention(
     q: jax.Array,
@@ -26,7 +26,6 @@ def flash_attention(
     prefix: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ):
     """q: (B, Sq, H, D); k/v: (B, Sk, KH, D). Returns (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
@@ -45,6 +44,6 @@ def flash_attention(
     out = flash_attention_call(
         qr, kr, vr,
         n_q_per_kv=G, block_q=bq, block_k=bk,
-        causal=causal, window=window, prefix=prefix, interpret=interpret,
+        causal=causal, window=window, prefix=prefix,
     )
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

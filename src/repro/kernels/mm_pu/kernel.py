@@ -17,13 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU scratch memory spaces; interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro import kernels
 
 
 def _apply_activation(x, activation: str):
@@ -93,7 +89,6 @@ def mm_pu_call(
     w_scale=None,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
 ):
     """x: (M, K); w: (K, N) [int8 if w_scale given]; bias: (1, N);
     residual: (M, N); w_scale: (1, N). Dims must be multiples of the blocks
@@ -129,11 +124,7 @@ def mm_pu_call(
         has_residual=residual is not None,
         int8_w=int8_w,
     )
-    scratch = (
-        [_VMEM((block_m, block_n), jnp.float32)]
-        if _VMEM is not None
-        else [pl.BlockSpec.memory_space]  # pragma: no cover
-    )
+    scratch = [pltpu.VMEM((block_m, block_n), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(M // block_m, N // block_n, nk),
@@ -141,5 +132,5 @@ def mm_pu_call(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(*args)

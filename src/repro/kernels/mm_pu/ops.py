@@ -37,7 +37,7 @@ def pad_overhead(m: int, n: int, k: int, spec: MMTileSpec) -> float:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "spec", "activation", "out_dtype", "interpret",
+        "spec", "activation", "out_dtype",
     ),
 )
 def mm_pu(
@@ -50,7 +50,6 @@ def mm_pu(
     w_scale: Optional[jax.Array] = None,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
 ):
     """x: (M, K) @ w: (K, N) with fused epilogue. Returns (M, N)."""
     M, K = x.shape
@@ -69,6 +68,6 @@ def mm_pu(
         xp, wp,
         block_m=bm, block_n=bn, block_k=bk,
         bias=biasp, residual=resp, w_scale=scalep,
-        activation=activation, out_dtype=out_dtype, interpret=interpret,
+        activation=activation, out_dtype=out_dtype,
     )
     return out[:M, :N]

@@ -15,10 +15,13 @@ in HBM.
 Layouts (ops.py does the model-layout shuffle):
   q       (B, KH, G*W, D)   row r of slot b = query i = r % W of group
                             g = r // W, at absolute position lens[b] + i
-  pools   (N, bs, KH, D)    k/v pages (+ (N, bs, KH, 1) fp32 scales for
-                            int8); 16-bit float pools arrive bitcast to
-                            int16 (bits are bits for a DMA, and the
+  pools   (N, bs, KH, D)    k/v pages; 16-bit float pools arrive bitcast
+                            to int16 (bits are bits for a DMA, and the
                             interpreter's bf16 copy path is pathological)
+  scales  (N, 1, P) f32    int8 pools only: one scale per (token, head)
+                            at lane i * KH + h, P = bs * KH rounded up to
+                            128 lanes, so a page's scales are one
+                            lane-aligned DMA (core/plan.int8_scale_lanes)
   table   (B, MB) int32     scalar-prefetched; block 0 is the trash block
   lens    (B)    int32      positions already cached per slot
   q_lens  (B)    int32      live query rows (0 idle / 1 decode / <=W prefill)
@@ -37,16 +40,42 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro import kernels
 
 NEG_INF = -1e30
+
+# VMEM one grid step holds per element of its page tile (ppt * bs * KH * D
+# elements): the DMA'd tile plus the widened, dequantized and re-laid-out
+# copies Mosaic keeps live around the two dots.  An upper bound fitted to
+# compile probes on a described v5e (KH 4-8, D 128, bs 16, slab 1-256,
+# f32 queries), where it measured 38-44 B (16-bit pages), 56-64 B (int8)
+# and 68 B (fp32).
+_TILE_BYTES_PER_ELEM = {"bf16": 48, "int8": 72, "fp32": 72}
+
+
+def _padded(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def vmem_bytes(
+    *, kv_dtype: str, q_itemsize: int, kv_heads: int, rows: int,
+    head_dim: int, block_size: int, pages_per_tile: int,
+) -> int:
+    """VMEM the kernel needs for one (slot, tile) grid step.
+
+    ``rows`` = G * W query rows per KV head.  Counts the double-buffered q
+    and output blocks, the (m, l, acc) carry, the two (KH, rows, T) f32
+    score/probability arrays and the page tile with its in-kernel copies,
+    each padded to the (8 x 128) vreg tiling the way Mosaic lays it out."""
+    KH, D, T = kv_heads, _padded(head_dim, 128), pages_per_tile * block_size
+    sub = 8 * (4 // max(q_itemsize, 1))  # sublanes of one q/out vreg tile
+    blocks = 4 * KH * _padded(rows, sub) * D * q_itemsize
+    carry = KH * _padded(rows, 8) * D * 4 + 2 * _padded(KH, 8) * _padded(rows, 128) * 4
+    scores = 2 * KH * _padded(rows, 8) * _padded(T, 128) * 4
+    tile = T * _padded(KH, 8) * head_dim * _TILE_BYTES_PER_ELEM[kv_dtype]
+    return blocks + carry + scores + tile
 
 
 def _paged_kernel(
@@ -118,20 +147,26 @@ def _paged_kernel(
         KH, GW, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
         T = ppt * bs
 
-        def pages(tile):  # (ppt, bs, KH, x) -> (T, KH, x), layout untouched
-            tile = tile.reshape(T, KH, tile.shape[-1])
+        def pages(tile, st=None):  # (ppt, bs, KH, D) -> (T, KH, D)
             if tile.dtype == jnp.int16:
                 # bf16 bits in an int16 carrier — re-tag and keep the MXU
                 # operand in bf16 (f32 accumulate): no widening pass over
                 # the tile, the matmul upconverts in-register.
-                return jax.lax.bitcast_convert_type(tile, jnp.bfloat16)
-            return tile.astype(jnp.float32)
+                return jax.lax.bitcast_convert_type(
+                    tile.reshape(T, KH, D), jnp.bfloat16
+                )
+            x = tile.astype(jnp.float32)
+            if st is not None:
+                # int8 dequant on the per-(token, head) grid: scales
+                # (ppt, 1, P) hold token i, head h of page p at lane
+                # i * KH + h; as a (ppt, bs * KH, 1) column they broadcast
+                # over D
+                sc = st.reshape(ppt, st.shape[-1])[:, : bs * KH]
+                x = x.reshape(ppt, bs * KH, D) * sc[:, :, None]
+            return x.reshape(T, KH, D)
 
-        k = pages(kt[...])
-        v = pages(vt[...])
-        if quantized:  # in-kernel dequant on the per-(token, head) grid
-            k = k * kst[...].reshape(T, KH, 1)
-            v = v * vst[...].reshape(T, KH, 1)
+        k = pages(kt[...], kst[...] if quantized else None)
+        v = pages(vt[...], vst[...] if quantized else None)
 
         # Pages stay in their DMA'd (token, head, d) layout; the head dim
         # rides as a dot_general batch dim so no in-VMEM transpose is paid.
@@ -188,10 +223,10 @@ def paged_attention_call(
     pages_per_tile: int,
     window: int = 0,
     softmax_scale=None,
-    interpret: bool = True,
 ):
-    """q: (B, KH, G*W, D) kernel layout; pools (N, bs, KH, D); returns the
-    same (B, KH, G*W, D).  ``pages_per_tile`` must divide the table width."""
+    """q: (B, KH, G*W, D) kernel layout; pools (N, bs, KH, D), int8 scales
+    (N, 1, P); returns the same (B, KH, G*W, D).  ``pages_per_tile`` must
+    divide the table width."""
     B, KH, GW, D = q.shape
     MB = table.shape[1]
     bs = block_size
@@ -212,15 +247,16 @@ def paged_attention_call(
         W=slab, bs=bs, ppt=ppt, nt=nt, window=window, scale=scale,
         quantized=quantized,
     )
-    pool_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    scale_tile = (ppt,) + k_scale.shape[1:] if quantized else (1, 1, 128)
     scratch = [
-        _VMEM((ppt, bs, KH, D), k_pages.dtype),  # k tile
-        _VMEM((ppt, bs, KH, D), k_pages.dtype),  # v tile
-        _VMEM((ppt, bs, KH, 1), jnp.float32),  # k scales (int8 only)
-        _VMEM((ppt, bs, KH, 1), jnp.float32),  # v scales
-        _VMEM((KH, GW), jnp.float32),  # m
-        _VMEM((KH, GW), jnp.float32),  # l
-        _VMEM((KH, GW, D), jnp.float32),  # acc
+        pltpu.VMEM((ppt, bs, KH, D), k_pages.dtype),  # k tile
+        pltpu.VMEM((ppt, bs, KH, D), k_pages.dtype),  # v tile
+        pltpu.VMEM(scale_tile, jnp.float32),  # k scales (int8 only)
+        pltpu.VMEM(scale_tile, jnp.float32),  # v scales
+        pltpu.VMEM((KH, GW), jnp.float32),  # m
+        pltpu.VMEM((KH, GW), jnp.float32),  # l
+        pltpu.VMEM((KH, GW, D), jnp.float32),  # acc
         pltpu.SemaphoreType.DMA((4,)),
     ]
     if not quantized:  # keep operand count static: pass the pools twice
@@ -238,5 +274,5 @@ def paged_attention_call(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, GW, D), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(table, lens, q_lens, q, k_pages, v_pages, k_scale, v_scale)

@@ -18,7 +18,7 @@ from repro.kernels.paged_attention.kernel import paged_attention_call
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "window", "pages_per_tile", "interpret"),
+    static_argnames=("block_size", "window", "pages_per_tile"),
 )
 def paged_attention(
     q: jax.Array,
@@ -30,7 +30,6 @@ def paged_attention(
     block_size: int,
     window: int = 0,
     pages_per_tile: int = 0,
-    interpret: bool = True,
 ) -> jax.Array:
     """q: (B, W, H, D) model layout; entry: paged pool entry
     ({"k","v"[,"k_scale","v_scale"]}, models/cache layout); table (B, MB);
@@ -59,6 +58,5 @@ def paged_attention(
         block_size=block_size,
         pages_per_tile=ppt,
         window=window,
-        interpret=interpret,
     )
     return out.reshape(B, KH, G, W, D).transpose(0, 3, 1, 2, 4).reshape(B, W, H, D)

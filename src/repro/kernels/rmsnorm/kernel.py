@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _rmsnorm_kernel(x_ref, s_ref, *rest, eps: float, has_residual: bool):
     if has_residual:
@@ -37,7 +39,6 @@ def rmsnorm_call(
     *,
     block_rows: int = 256,
     eps: float = 1e-6,
-    interpret: bool = True,
 ):
     """x: (N, d); scale: (d,); residual: (N, d) or None -> (N, d)."""
     N, d = x.shape
@@ -61,5 +62,5 @@ def rmsnorm_call(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(*args)

@@ -8,13 +8,12 @@ import jax
 from repro.kernels.rmsnorm.kernel import rmsnorm_call
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm(x, scale, residual=None, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
+def rmsnorm(x, scale, residual=None, *, eps: float = 1e-6, block_rows: int = 256):
     shape = x.shape
     xf = x.reshape(-1, shape[-1])
     rf = residual.reshape(-1, shape[-1]) if residual is not None else None
     out = rmsnorm_call(
-        xf, scale, rf, block_rows=block_rows, eps=eps, interpret=interpret
+        xf, scale, rf, block_rows=block_rows, eps=eps
     )
     return out.reshape(shape)

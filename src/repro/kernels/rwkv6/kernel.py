@@ -20,13 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro import kernels
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *, c: int):
@@ -80,7 +76,6 @@ def wkv_call(
     *,
     n_heads: int,
     chunk: int = 64,
-    interpret: bool = True,
 ):
     """r/k/v/logw: (BH, S, D) with BH = B * n_heads; u: (H, D)."""
     BH, S, D = r.shape
@@ -99,6 +94,6 @@ def wkv_call(
         ],
         out_specs=pl.BlockSpec((1, chunk, D), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), r.dtype),
-        scratch_shapes=[_VMEM((D, D), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
+        interpret=kernels.interpret_mode(),
     )(r, k, v, logw, u)

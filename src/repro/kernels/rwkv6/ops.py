@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from repro.kernels.rwkv6.kernel import wkv_call
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def wkv(
     r: jax.Array,
     k: jax.Array,
@@ -18,7 +18,6 @@ def wkv(
     u: jax.Array,
     *,
     chunk: int = 64,
-    interpret: bool = True,
 ):
     """r/k/v/w: (B, S, H, D) with w the decay in (0,1); u: (H, D)."""
     B, S, H, D = r.shape
@@ -29,6 +28,6 @@ def wkv(
     logw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-12))
     out = wkv_call(
         to_k(r), to_k(k), to_k(v), to_k(logw), u,
-        n_heads=H, chunk=c, interpret=interpret,
+        n_heads=H, chunk=c,
     )
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

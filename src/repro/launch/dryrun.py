@@ -173,8 +173,6 @@ def run_cell(arch, shape, *, multi_pod, force=False, out_dir=RESULTS,
             t2 = time.time()
             ma = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # jax<=0.4.x returns [dict]
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
             rep = analyze(
                 arch=arch,
@@ -306,8 +304,7 @@ def main():
             max_seq=96, batch=3, fix_batch=True, prefill_chunk=16,
             hardware=a.hardware,
         )
-        summary = run_batched(sargs, cfg, make_host_mesh())
-        cal = summary["calibration"]
+        cal = run_batched(sargs, cfg, make_host_mesh()).summary()["calibration"]
         print()
         print(f"planner drift calibration ({arch}, roofline priced as {a.hardware}):")
         for phase, rep in (cal.get("phases") or {}).items():

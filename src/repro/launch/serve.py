@@ -47,9 +47,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core.hardware import get_hardware
-from repro.core.plan import derive_plan, derive_serve_plan, serve_feasible
+from repro.core.hardware import resolve_hardware
+from repro.core.plan import (
+    SERVE_WEIGHT_DTYPE,
+    derive_plan,
+    derive_serve_plan,
+    serve_feasible,
+)
 from repro.dist.sharding import Shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.params import init_params
 from repro.serve import (
@@ -106,7 +112,9 @@ class ServeArgs:
     chaos_spike_ms: float = 0.0
     chaos_horizon: Optional[int] = None
     # ---- device + family pick ----
-    hardware: str = "tpu_v5e"  # registered HardwareSpec the plans derive from
+    # registered HardwareSpec the plans derive from off-TPU; on a TPU the
+    # chip's device_kind decides (core/hardware.resolve_hardware)
+    hardware: str = "tpu_v5e"
     # Pick the serving plan off the design-space Pareto frontier instead of
     # deriving one: "throughput" | "cost" | "energy" (core/search.py;
     # docs/PLANNER.md).  Individual plan-override flags are ignored then.
@@ -238,8 +246,10 @@ def pick_from_family(a: ServeArgs, cfg, mesh, hw):
     return point.plan
 
 
-def run_batched(a: ServeArgs, cfg, mesh) -> dict:
-    hw = get_hardware(a.hardware)
+def run_batched(a: ServeArgs, cfg, mesh):
+    """Serve ``a``'s request stream on ``mesh``; returns the drained
+    engine (its ``summary()`` is printed)."""
+    hw = resolve_hardware(a.hardware)
     plan = derive_plan(
         cfg, dict(mesh.shape), hw,
         batch=a.batch, seq_len=a.prompt_len, training=False,
@@ -251,8 +261,12 @@ def run_batched(a: ServeArgs, cfg, mesh) -> dict:
     print(plan.describe())
     print(serve.describe())
     sh = Shardings(mesh, plan, cfg)
-    params = init_params(jax.random.PRNGKey(0), cfg, plan, dtype=jnp.float32)
-    params = jax.device_put(params, sh.param_shardings(params))
+    # one program draws the weights straight into their placement (eager
+    # init holds every layer twice, and then a placed copy)
+    init = lambda: init_params(  # noqa: E731
+        jax.random.PRNGKey(0), cfg, plan, dtype=SERVE_WEIGHT_DTYPE
+    )
+    params = jax.jit(init, out_shardings=sh.param_shardings(jax.eval_shape(init)))()
     draft = None
     draft_name = a.draft
     if a.from_family and not draft_name and serve.spec_len > 0:
@@ -297,15 +311,15 @@ def run_batched(a: ServeArgs, cfg, mesh) -> dict:
     if cal.get("overall_ratio"):
         print(f"planner calibration: {cal['note']}")
     print(json.dumps(summary, indent=1, default=str))
-    return summary
+    return engine
 
 
 def run_eager(a: ServeArgs, cfg, mesh) -> dict:
     plan = derive_plan(
-        cfg, dict(mesh.shape), get_hardware(a.hardware),
+        cfg, dict(mesh.shape), resolve_hardware(a.hardware),
         batch=a.batch, seq_len=a.prompt_len, training=False,
     )
-    params = init_params(jax.random.PRNGKey(0), cfg, plan, dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg, plan, dtype=SERVE_WEIGHT_DTYPE)
     key = jax.random.PRNGKey(1)
     batch = {
         "tokens": jax.random.randint(key, (a.batch, a.prompt_len), 0, cfg.vocab_size)
@@ -406,8 +420,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="iteration after which no new fault fires (lets a "
                          "chaotic stream drain deterministically)")
     ap.add_argument("--hardware", default="tpu_v5e",
-                    help="registered HardwareSpec name the plans derive from "
-                         "(variants: repro.core.hardware.HARDWARE_VARIANTS)")
+                    help="registered HardwareSpec the plans derive from when "
+                         "the backend is not a TPU (variants: "
+                         "repro.core.hardware.HARDWARE_VARIANTS); on a TPU "
+                         "the chip's device_kind decides")
     ap.add_argument("--from-family", default=None,
                     choices=[None, "throughput", "cost", "energy"],
                     help="pick the serving plan off the design-space Pareto "
@@ -439,6 +455,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     a = ServeArgs.from_namespace(build_parser().parse_args())
+    enable_compile_cache()
     cfg = get_config(a.arch)
     mesh = make_host_mesh()
     if a.engine == "batched" and not serve_feasible(cfg)[0]:

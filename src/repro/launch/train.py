@@ -30,10 +30,11 @@ import jax.numpy as jnp
 
 from repro.ckpt.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import get_config
-from repro.core.hardware import TPU_V5E
+from repro.core.hardware import TPU_V5E, resolve_hardware
 from repro.core.plan import derive_plan
 from repro.data.pipeline import DataConfig, DataIterator
 from repro.dist.sharding import Shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.params import init_params
 from repro.dist.pipeline import bubble_fraction
@@ -88,7 +89,8 @@ def run(
         dp = max(1, len(jax.devices()) // pipeline)
     mesh = make_host_mesh(pod=pipeline if pipeline > 1 else 1, data=dp)
     plan = derive_plan(
-        cfg, dict(mesh.shape), TPU_V5E, batch=batch, seq_len=seq, training=True,
+        cfg, dict(mesh.shape), resolve_hardware(TPU_V5E.name),
+        batch=batch, seq_len=seq, training=True,
         pod_role="pipeline" if pipeline > 1 else "data",
         seq_parallel=seq_parallel, grad_compression=compression,
         force_mode=force_mode,
@@ -194,6 +196,7 @@ def main():
     )
     ap.add_argument("--force-mode", default=None, choices=["spatial", "temporal"])
     a = ap.parse_args()
+    enable_compile_cache()
     losses, _ = run(
         a.arch, steps=a.steps, batch=a.batch, seq=a.seq, lr=a.lr,
         ckpt_dir=a.ckpt_dir, ckpt_every=a.ckpt_every, resume=a.resume,

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.plan import ExecutionPlan
+from repro.core.plan import ExecutionPlan, int8_scale_lanes
 
 PyTree = Any
 
@@ -122,12 +122,16 @@ def _paged_layer_entry(cfg: ArchConfig, serve) -> dict:
     KV, Dh = cfg.n_kv_heads, cfg.d_head
     N, bs = serve.n_blocks, serve.block_size
     if serve.kv_dtype == "int8":
+        P = int8_scale_lanes(KV, bs)
         return {
             "paged": {
                 "k": jnp.zeros((N, bs, KV, Dh), jnp.int8),
                 "v": jnp.zeros((N, bs, KV, Dh), jnp.int8),
-                "k_scale": jnp.zeros((N, bs, KV, 1), jnp.float32),
-                "v_scale": jnp.zeros((N, bs, KV, 1), jnp.float32),
+                # one scale per (token, head) at lane i * KV + h of the
+                # page's row: the kernel fetches a page's scales as one
+                # lane-aligned (1, P) DMA
+                "k_scale": jnp.zeros((N, 1, P), jnp.float32),
+                "v_scale": jnp.zeros((N, 1, P), jnp.float32),
             }
         }
     dt = {"bf16": jnp.bfloat16, "fp32": jnp.float32}[serve.kv_dtype]
@@ -218,14 +222,21 @@ def paged_update(
         fp = fp.at[flat].set(val.reshape((B * S,) + val.shape[2:]).astype(fp.dtype))
         return fp.reshape(pool.shape)
 
+    def put_scale(pool, val):  # (N, 1, P) pool, (B, S, KV, 1) scales
+        KV = val.shape[2]
+        lane = (flat % block_size)[:, None] * KV + jnp.arange(KV)[None, :]
+        return pool.at[(flat // block_size)[:, None], 0, lane].set(
+            val.reshape(B * S, KV)
+        )
+
     out = dict(entry)
     if "k_scale" in entry:
         qk, sk = quantize(k.astype(jnp.float32), "int8", _kv_vec_scale(k))
         qv, sv = quantize(v.astype(jnp.float32), "int8", _kv_vec_scale(v))
         out["k"] = put(entry["k"], qk)
         out["v"] = put(entry["v"], qv)
-        out["k_scale"] = put(entry["k_scale"], sk)
-        out["v_scale"] = put(entry["v_scale"], sv)
+        out["k_scale"] = put_scale(entry["k_scale"], sk)
+        out["v_scale"] = put_scale(entry["v_scale"], sv)
     else:
         out["k"] = put(entry["k"], k)
         out["v"] = put(entry["v"], v)
@@ -267,10 +278,15 @@ def paged_gather(
     def take(pool):
         return pool.reshape((-1,) + pool.shape[2:])[flat]
 
+    def take_scale(pool):  # (N, 1, P) -> (B, L*bs, KV, 1)
+        KV = entry["k"].shape[2]
+        lane = (flat % block_size)[..., None] * KV + jnp.arange(KV)
+        return pool[(flat // block_size)[..., None], 0, lane][..., None]
+
     k, v = take(entry["k"]), take(entry["v"])
     if "k_scale" in entry:
-        k = dequantize(k, take(entry["k_scale"]), "int8")
-        v = dequantize(v, take(entry["v_scale"]), "int8")
+        k = dequantize(k, take_scale(entry["k_scale"]), "int8")
+        v = dequantize(v, take_scale(entry["v_scale"]), "int8")
     return k, v
 
 

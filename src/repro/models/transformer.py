@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.configs.base import ArchConfig
@@ -366,7 +366,7 @@ def sp_stack_forward(
         mesh=mesh,
         in_specs=(stack_specs, x_spec, PartitionSpec(None, None)),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(stack, x, positions)
 
 
@@ -405,12 +405,27 @@ def _run_stack(
     if blocks["stack"] is not None:
         stack_caches = None if caches is None else caches["stack"]
         if stack_caches is None:
-            n = jax.tree.leaves(blocks["stack"])[0].shape[0]
-            stack_caches = None
             xs = (blocks["stack"], _nones_like_scan(blocks["stack"]))
+            x, (new_stack, auxes) = lax.scan(body, x, xs)
         else:
-            xs = (blocks["stack"], stack_caches)
-        x, (new_stack, auxes) = lax.scan(body, x, xs)
+            # The caches ride in the carry and each group's slice is updated
+            # in place.  As scanned outputs they would be a second copy of
+            # every cache (for the serving engine, the whole KV pool) in HBM.
+            def carried(carry, gp):
+                x, stack, i = carry
+                gcache = jax.tree.map(
+                    lambda c: lax.dynamic_index_in_dim(c, i, keepdims=False), stack
+                )
+                x, (nc, aux) = body(x, (gp, gcache))
+                stack = jax.tree.map(
+                    lambda c, n: lax.dynamic_update_index_in_dim(c, n, i, 0),
+                    stack, nc,
+                )
+                return (x, stack, i + 1), aux
+
+            (x, new_stack, _), auxes = lax.scan(
+                carried, (x, stack_caches, jnp.int32(0)), blocks["stack"]
+            )
         total_aux += auxes.sum()
     new_tail = []
     for i, lp in enumerate(blocks["tail"]):

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.hardware import DEFAULT_HARDWARE, HardwareSpec
+from repro.core.hardware import DEFAULT_HARDWARE, HardwareSpec, resolve_hardware
 from repro.core.plan import ExecutionPlan, ServePlan, serve_feasible
 from repro.models.cache import (
     cache_from_prefill,
@@ -276,6 +276,23 @@ def make_rolled_step(
     return jax.jit(rolled_fn, donate_argnums=(1,))
 
 
+def traces_bounded(trace_counts: dict) -> bool:
+    """The engine's no-retrace contract: at most TWO compiled device
+    programs in normal operation — the unified mixed step (exactly once)
+    and, when rolling is enabled and engaged, the rolled decode loop (at
+    most once).  Request churn, draft depth, horizon K and the chaos
+    harness's NaN-poison vector are data, never shapes.  The one sanctioned
+    extra compile is the degradation ladder's bottom rung: the eager gather
+    fallback (``fallback_step``), built lazily and at most once, and only
+    after transient faults exhausted the fused rungs."""
+    return (
+        set(trace_counts) <= {"step", "rolled_step", "fallback_step"}
+        and trace_counts.get("step") == 1
+        and trace_counts.get("rolled_step", 0) <= 1
+        and trace_counts.get("fallback_step", 0) <= 1
+    )
+
+
 def _by_tenant(finished: list) -> dict:
     groups: dict = {}
     for r in finished:
@@ -362,14 +379,20 @@ class ServingEngine:
         # arithmetic), lifecycle tracing only when the caller enabled it on
         # the bundle — emission can never touch shapes or device work
         self.obs = obs if obs is not None else Observability()
-        self.hw = hw if hw is not None else DEFAULT_HARDWARE
+        self.hw = hw if hw is not None else resolve_hardware(DEFAULT_HARDWARE.name)
         self.sched = Scheduler(serve, obs=self.obs)
         self.params = params
-        self.pools = init_paged_cache(cfg, plan, serve)
-        if shardings is not None:
-            self.pools = jax.device_put(
-                self.pools, shardings.cache_shardings(self.pools)
-            )
+        # One program writes the zero pools at their final placement: built
+        # eagerly, the per-layer pools, their stack and a placed copy would
+        # all be live at once, and at full size they do not fit next to the
+        # weights.
+        pools_fn = lambda: init_paged_cache(cfg, plan, serve)  # noqa: E731
+        self.pools = jax.jit(
+            pools_fn,
+            out_shardings=None
+            if shardings is None
+            else shardings.cache_shardings(jax.eval_shape(pools_fn)),
+        )()
         shard = shardings.constrain if shardings is not None else Identity
         self._shard = shard
         self.injector = injector

@@ -41,7 +41,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hardware import DEFAULT_HARDWARE, HardwareSpec
-from repro.core.plan import ExecutionPlan, ServePlan, derive_plan, derive_serve_plan
+from repro.core.plan import (
+    SERVE_WEIGHT_DTYPE,
+    ExecutionPlan,
+    ServePlan,
+    derive_plan,
+    derive_serve_plan,
+)
 from repro.models.cache import init_paged_cache
 from repro.serve.engine import make_mixed_step
 from repro.serve.scheduler import BlockAllocator
@@ -326,7 +332,9 @@ def make_draft_source(
         kv_dtype=target_serve.kv_dtype,
     )
     if params is None:
-        params = init_params(jax.random.PRNGKey(seed), cfg, plan, dtype=jnp.float32)
+        params = init_params(
+            jax.random.PRNGKey(seed), cfg, plan, dtype=SERVE_WEIGHT_DTYPE
+        )
     return ModelDraft(
         params, cfg, plan, serve_d, target_vocab=target_cfg.vocab_size
     )

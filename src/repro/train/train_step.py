@@ -31,7 +31,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -189,7 +189,7 @@ def make_train_step(
                 mesh=mesh,
                 in_specs=(P(), batch_specs),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )(state.params, batch)
             if grad_shardings is not None:
                 grads = jax.lax.with_sharding_constraint(grads, grad_shardings)

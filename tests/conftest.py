@@ -14,20 +14,11 @@ except ImportError:
 
 
 def assert_traces_bounded(trace_counts: dict) -> None:
-    """The serving engine's no-retrace contract: at most TWO compiled
-    device programs in normal operation — the unified mixed step (exactly
-    once) and, when rolling is enabled and engaged, the rolled decode loop
-    (at most once).  Request churn, draft depth, horizon K and the chaos
-    harness's NaN-poison vector are data, never shapes.  The one sanctioned
-    extra compile is the degradation ladder's bottom rung: the eager gather
-    fallback (``fallback_step``), built lazily and at most once, and only
-    after transient faults exhausted the fused rungs."""
-    assert set(trace_counts) <= {"step", "rolled_step", "fallback_step"}, (
-        trace_counts
-    )
-    assert trace_counts["step"] == 1, trace_counts
-    assert trace_counts.get("rolled_step", 0) <= 1, trace_counts
-    assert trace_counts.get("fallback_step", 0) <= 1, trace_counts
+    """The serving engine's no-retrace contract
+    (``repro.serve.engine.traces_bounded``)."""
+    from repro.serve.engine import traces_bounded
+
+    assert traces_bounded(trace_counts), trace_counts
 
 
 @pytest.fixture(scope="session")

@@ -13,7 +13,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.dist.collectives import overlap_all_gather_matmul, compressed_psum
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = jax.make_mesh((4,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 key = jax.random.PRNGKey(0)
@@ -44,7 +44,7 @@ def test_ring_matmul_and_compressed_psum_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", _SNIPPET],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd=".",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"}, cwd=".",
     )
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])

@@ -116,3 +116,15 @@ def test_energy_params_empty_without_tech_node():
 def test_spec_stays_hashable():
     hash(TPU_V5E)
     hash(get_hardware("vck5000-int8w"))
+
+
+def test_device_kind_resolves_the_chip_spec():
+    """On a TPU the chip's device_kind picks the spec; an unknown kind
+    raises instead of planning against some other chip's numbers."""
+    from repro.core.hardware import hardware_for_kind, resolve_hardware
+
+    assert hardware_for_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="TPU v9 imaginary"):
+        hardware_for_kind("TPU v9 imaginary")
+    # off-TPU the named registered device is the planning target
+    assert resolve_hardware("vck5000").name == "vck5000"

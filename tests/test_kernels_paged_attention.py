@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.plan import int8_scale_lanes
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
@@ -13,9 +14,19 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _quantize(x):
-    """train/compression.quantize's per-(token, kv-head) int8 grid."""
+    """train/compression.quantize's per-(token, kv-head) int8 grid; pages
+    (N, bs, KH, D) int8, scales in the pool's (N, 1, P) row layout."""
     s = jnp.maximum(jnp.abs(x).max(-1, keepdims=True), 1e-12) / 127.0
-    return jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8), s
+    q = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8)
+    return q, _scale_rows(s)
+
+
+def _scale_rows(s):
+    """(N, bs, KH, 1) scales -> the pool's token-major (N, 1, P) rows."""
+    N, bs, KH, _ = s.shape
+    rows = s.reshape(N, 1, bs * KH)
+    pad = int8_scale_lanes(KH, bs) - bs * KH
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
 
 
 def _mk_case(B, W, H, KH, D, bs, MB, kv_dtype, seed=0):

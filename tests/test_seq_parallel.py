@@ -82,7 +82,7 @@ def test_seq_parallel_numerics_and_hlo_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", _SNIPPET],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}, cwd=".",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"}, cwd=".",
     )
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])

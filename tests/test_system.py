@@ -78,7 +78,7 @@ def test_sharded_dryrun_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", _DRYRUN_SNIPPET],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"},
         cwd=".",
     )
     assert r.returncode == 0, r.stderr[-2000:]
