@@ -7,13 +7,13 @@ qwen3-1.7b (28 layers, random weights from a fixed seed).
 One chip: 8 staggered requests (prompt 128, gen 32) go through
 ``launch/serve.run_batched`` with the plan ``derive_serve_plan`` derives
 for the chip.  The run must finish every request with its token count, use
-the fused paged-attention kernel compiled by Mosaic (``tpu_custom_call`` in
-the step's HLO) and compile each engine program at most once.  When the
-derived plan does not roll decode steps, the same requests run again with
-the rolled loop (4 steps per dispatch), so both engine programs run.  The
-same requests then run on a gather-path engine (dense attention) and the
-tokens are compared, as are the first-step logits of the two attention
-paths (tolerance below).
+the fused paged-attention kernel compiled by Mosaic (a ``tpu_custom_call``
+in the step's HLO that carries the kernel's name) and compile each engine
+program at most once.  When the derived plan does not roll decode steps,
+the same requests run again with the rolled loop (4 steps per dispatch), so
+both engine programs run.  The same requests then run on a gather-path
+engine (dense attention) and the tokens are compared, as are the first-step
+logits of the two attention paths (tolerance below).
 
 Four chips: the same requests on a (data=1, model=4) mesh and on a
 one-device mesh with the same plan, tokens and first-step logits compared.
@@ -39,6 +39,7 @@ import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.dist.sharding import Shardings  # noqa: E402
+from repro.kernels.paged_attention.kernel import NAME as KERNEL  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.launch.serve import ServeArgs, run_batched  # noqa: E402
@@ -182,8 +183,11 @@ def one_chip(cfg) -> None:
     check(traces_bounded(engine.trace_counts), f"traces {engine.trace_counts}")
     hlo = step_hlo(engine)
     check("tpu_custom_call" in hlo, "no Mosaic kernel in the served step's HLO")
+    check(any("tpu_custom_call" in line and f"/{KERNEL}/pallas_call" in line
+              for line in hlo.splitlines()),
+          f"the served step's Mosaic kernel is not named {KERNEL!r}")
     log(f"served step: fused={engine.fused} traces={engine.trace_counts} "
-        f"tpu_custom_call in HLO: True")
+        f"tpu_custom_call named {KERNEL!r} in HLO: True")
     params, plan, sp, hw = engine.params, engine.plan, engine.serve, engine.hw
     sh = Shardings(mesh, plan, cfg)  # place new pools as run_batched did
     release(engine)

@@ -55,6 +55,11 @@ NEG_INF = -1e30
 _TILE_BYTES_PER_ELEM = {"bf16": 48, "int8": 72, "fp32": 72}
 
 
+# The kernel's name: the scope of its custom call in the compiled HLO's op
+# metadata (``.../paged_attention/pallas_call``), where a trace reader finds it.
+NAME = "paged_attention"
+
+
 def _padded(n: int, m: int) -> int:
     return -(-n // m) * m
 
@@ -275,4 +280,5 @@ def paged_attention_call(
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, GW, D), q.dtype),
         interpret=kernels.interpret_mode(),
+        name=NAME,
     )(table, lens, q_lens, q, k_pages, v_pages, k_scale, v_scale)

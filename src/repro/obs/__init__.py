@@ -7,7 +7,10 @@ Three layers, one bundle:
   + JSON snapshot (``launch/serve.py --metrics-out``);
 * :class:`~repro.obs.trace.Tracer` — ring-buffered per-request lifecycle
   spans and per-dispatch step spans, exported as Chrome ``trace_event``
-  JSON for Perfetto (``--trace-out`` / ``--trace-buffer``);
+  JSON for Perfetto (``--trace-out`` / ``--trace-buffer``), and
+  :class:`~repro.obs.trace.Phases`, the engine step's phases, mirrored
+  into the profiler's trace (``jax.profiler.TraceAnnotation``) and timed
+  into ``engine.stats`` (``admit_s`` ... ``harvest_s``);
 * :class:`~repro.obs.calibrate.DriftMeter` — predicted (roofline) vs
   measured wall time per dispatch, per phase — the planner's calibration
   signal (``engine.summary()["calibration"]``, ``dryrun --calibrate``).
@@ -42,6 +45,8 @@ from repro.obs.trace import (
     PID_REQUESTS,
     TID_DISPATCH,
     TID_FAULTS,
+    TID_PHASES,
+    Phases,
     Tracer,
     validate_chrome_trace,
 )
@@ -50,9 +55,10 @@ from repro.obs.trace import (
 class Observability:
     """The per-engine observability bundle + its emission API.
 
-    Every hook is host-side accounting only — no jax calls, no shapes, no
-    device work — so enabling or disabling observability can never perturb
-    the engine's byte output or its no-retrace contract.
+    Every hook is host-side accounting only — no shapes, no device work,
+    and no jax call but the profiler's host annotations — so enabling or
+    disabling observability can never perturb the engine's byte output or
+    its no-retrace contract.
     """
 
     def __init__(
@@ -158,10 +164,10 @@ class Observability:
             ("phase",),
         )
         self.m_ttft_ms = m.histogram(
-            "serve_ttft_ms", "Admit -> first token", ("tenant",)
+            "serve_ttft_ms", "Submit -> first token", ("tenant",)
         )
         self.m_latency_ms = m.histogram(
-            "serve_latency_ms", "Admit -> done (finished only)", ("tenant",)
+            "serve_latency_ms", "Submit -> done (finished only)", ("tenant",)
         )
 
     # ------------------------------------------------------ request events
@@ -189,13 +195,13 @@ class Observability:
 
     def on_finish(self, req, now: float) -> None:
         self.m_finished.inc(tenant=req.tenant, status="ok")
-        if req.t_admit is not None:
+        if req.t_submit is not None:
             if req.t_first is not None:
                 self.m_ttft_ms.observe(
-                    (req.t_first - req.t_admit) * 1e3, tenant=req.tenant
+                    (req.t_first - req.t_submit) * 1e3, tenant=req.tenant
                 )
             self.m_latency_ms.observe(
-                (now - req.t_admit) * 1e3, tenant=req.tenant
+                (now - req.t_submit) * 1e3, tenant=req.tenant
             )
         t0 = req.t_submit if req.t_submit is not None else now
         self.tracer.request_span(
@@ -330,11 +336,13 @@ __all__ = [
     "prometheus_roundtrip_ok",
     # tracing layer
     "Tracer",
+    "Phases",
     "validate_chrome_trace",
     "PID_ENGINE",
     "PID_REQUESTS",
     "TID_DISPATCH",
     "TID_FAULTS",
+    "TID_PHASES",
     # calibration layer
     "DriftMeter",
     "StepTimeModel",

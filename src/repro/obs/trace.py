@@ -7,7 +7,8 @@ Two kinds of tracks:
   (``step`` / ``rolled_step`` / ``fallback_step``), annotated with the slab
   composition, rolled-K and the degradation-ladder rung, plus an instant
   per injected fault (tid 1) tagged with the injector's (seed, salt,
-  iteration) so a chaos run is visually replayable;
+  iteration) so a chaos run is visually replayable, and the engine's step
+  phases (tid 2, ``engine.admit`` ... ``engine.harvest``; :class:`Phases`);
 * the **requests** process (pid 2) gives each request its own thread: the
   ``queued`` span, per-dispatch ``prefill-chunk`` / ``decode`` spans (their
   window is the enclosing step span's window, so lifecycles nest under
@@ -34,11 +35,14 @@ import json
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 # Chrome trace_event pids: one fake "process" per subsystem.
 PID_ENGINE = 1
 PID_REQUESTS = 2
 TID_DISPATCH = 0  # engine pid: device dispatches
 TID_FAULTS = 1  # engine pid: chaos injections
+TID_PHASES = 2  # engine pid: the phases of each engine step
 
 # Request threads cycle through a bounded id space so the rid -> tid map
 # stays O(1) memory on unbounded streams (collisions only recolor lanes in
@@ -149,6 +153,7 @@ class Tracer:
             _meta("process_name", PID_ENGINE, 0, "engine"),
             _meta("thread_name", PID_ENGINE, TID_DISPATCH, "dispatch"),
             _meta("thread_name", PID_ENGINE, TID_FAULTS, "faults"),
+            _meta("thread_name", PID_ENGINE, TID_PHASES, "phases"),
             _meta("process_name", PID_REQUESTS, 0, "requests"),
         ]
         for rid, tid in sorted(self._rid_tids.items()):
@@ -167,6 +172,64 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return sum(1 for e in doc["traceEvents"] if e["ph"] != "M")
+
+
+class Phases:
+    """Tiles a call into named phases, one clock read per boundary.
+
+    ``to(name)`` ends the open phase and begins ``name`` at the same
+    ``perf_counter`` reading, which it returns; leaving a ``with phases:``
+    block, on any path, ends the open phase.  Each phase goes to three
+    sinks:
+
+    * a ``jax.profiler.TraceAnnotation`` of its name while a profiler is
+      collecting, so it lands on the profiler's host plane on the device
+      ops' clock (otherwise the cost is TraceMe's inactive check);
+    * ``counters["<last part of the name>_s"]`` (``engine.sync`` ->
+      ``sync_s``), always: the seconds spent in it;
+    * a complete event on the tracer's ``phases`` track when the tracer is
+      enabled.
+
+    One object serves every call of its owner: it keeps one phase open at a
+    time, and with the profiler and the tracer off a boundary creates no
+    object but the clock's reading."""
+
+    def __init__(self, tracer: Tracer, counters: dict):
+        self.tracer = tracer
+        self.counters = counters
+        self._keys: dict = {}  # phase name -> counter key
+        self._name: Optional[str] = None
+        self._t0 = 0.0
+        self._note: Optional[TraceAnnotation] = None
+
+    def to(self, name: str) -> float:
+        t = time.perf_counter()
+        self._close(t)
+        if TraceAnnotation.is_enabled():
+            self._note = TraceAnnotation(name)
+            self._note.__enter__()
+        self._name, self._t0 = name, t
+        return t
+
+    def _close(self, t: float) -> None:
+        name = self._name
+        if name is None:
+            return
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
+        key = self._keys.get(name)
+        if key is None:
+            key = self._keys[name] = name.rsplit(".", 1)[-1] + "_s"
+        self.counters[key] += t - self._t0
+        self.tracer.complete(name, PID_ENGINE, TID_PHASES, self._t0, t)
+        self._name = None
+
+    def __enter__(self) -> "Phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._close(time.perf_counter())
 
 
 def _meta(name: str, pid: int, tid: int, label: str) -> dict:

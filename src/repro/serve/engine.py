@@ -41,7 +41,7 @@ from repro.models.cache import (
     paged_copy_block,
 )
 from repro.models.transformer import forward, logits_fn
-from repro.obs import Observability
+from repro.obs import Observability, Phases
 from repro.obs.calibrate import step_time_model
 from repro.serve.faults import (
     LADDER,
@@ -319,6 +319,13 @@ def _percentiles(xs: list) -> Optional[dict]:
     }
 
 
+def _since(reqs: list, start: str, end: str) -> Optional[dict]:
+    """Percentiles of ``end - start`` (two ``Request.t_*`` fields) over the
+    requests that stamped both."""
+    pairs = [(getattr(r, start), getattr(r, end)) for r in reqs]
+    return _percentiles([b - a for a, b in pairs if a is not None and b is not None])
+
+
 class ServingEngine:
     """Continuous-batching serving over the paged KV cache.
 
@@ -432,11 +439,16 @@ class ServingEngine:
             "steps": 0, "prefill_tokens": 0, "generated_tokens": 0,
             "draft_rows": 0, "accepted_drafts": 0, "spec_slots": 0,
             "spec_generated": 0, "fork_copies": 0, "occupancy_sum": 0.0,
-            "rolled_dispatches": 0, "rolled_steps": 0, "device_s": 0.0,
+            "rolled_dispatches": 0, "rolled_steps": 0,
             "retries": 0, "transient_faults": 0, "rung_escalations": 0,
             "rung_recoveries": 0, "quarantines": 0, "poisoned": 0,
             "expired": 0, "shed": 0, "cancelled": 0, "injected_nans": 0,
+            # seconds in each phase of step() (``self._phases``); they tile
+            # the call, and launch_s + sync_s is the dispatches' wall time
+            "admit_s": 0.0, "pack_s": 0.0, "launch_s": 0.0, "sync_s": 0.0,
+            "harvest_s": 0.0,
         }
+        self._phases = Phases(self.obs.tracer, self.stats)
         # degradation ladder: 0 = rolled-K spans, 1 = K=1 mixed step,
         # 2 = eager gather fallback (built lazily).  Transient-fault
         # retries that exhaust retry_limit step DOWN; ladder_recovery
@@ -662,11 +674,25 @@ class ServingEngine:
 
         Fork copies are applied immediately after admission, before anything
         can release blocks (growth/eviction run later in the iteration), so
-        a copy's source block is still resident when the device reads it."""
-        s = self.sched
+        a copy's source block is still resident when the device reads it.
+
+        The call is tiled into phases (``self._phases``; docs/OBSERVABILITY.md
+        §Engine phases): ``engine.admit`` (pressure, expiry, admission,
+        shedding, fork copies), ``engine.pack`` (the rolled plan, drafts,
+        growth, the slab, its price, the retry ladder, the poison vector),
+        ``engine.launch`` (the jitted call until it returns), ``engine.sync``
+        (blocking on its outputs) and ``engine.harvest`` (accept/rollback,
+        hooks, stats)."""
+        with self._phases:
+            self._iterate()
+
+    def _iterate(self) -> None:
+        """The body of ``step()``, marking each phase as it begins."""
+        s, ph = self.sched, self._phases
+        now = ph.to("engine.admit")
         if self.injector is not None:
             self.injector.pressure(self.iteration, s.alloc)
-        self.stats["expired"] += s.expire_deadlines(time.perf_counter())
+        self.stats["expired"] += s.expire_deadlines(now)
         s.admit(self.iteration)
         self.stats["shed"] += s.shed_starved(self.iteration)
         for src, dst in s.drain_copies():
@@ -674,6 +700,7 @@ class ServingEngine:
                 self.pools, jnp.int32(src), jnp.int32(dst)
             )
             self.stats["fork_copies"] += 1
+        ph.to("engine.pack")
         if self._rolled is not None and self.rung == 0:
             k, steps = s.plan_rolled(self.iteration, self.rolled_cap)
             if k > 1 and self._rolled_dispatch(k, steps):
@@ -711,7 +738,7 @@ class ServingEngine:
             poison = self._poison_vec(kinds)
             trace_key = "step" if self.rung < 2 else "fallback_step"
             traces_before = self.trace_counts[trace_key]
-            t0 = time.perf_counter()
+            t0 = ph.to("engine.launch")
             if self.injector is not None:
                 sp = self.injector.spike_s(self.iteration)
                 if sp:
@@ -720,12 +747,12 @@ class ServingEngine:
                 self.params, self.pools, tokens, tables, lens, kinds,
                 jnp.asarray(poison),
             )
+            ph.to("engine.sync")
             sampled = np.asarray(sampled)  # block for an honest step time
             vtok = np.asarray(vtok)
             finite = np.asarray(finite)
-            t1 = time.perf_counter()
+            t1 = ph.to("engine.harvest")
             dt_ms = (t1 - t0) * 1e3
-            self.stats["device_s"] += dt_ms / 1e3
             self._note_healthy()
             calibrated = self.trace_counts[trace_key] == traces_before
             if calibrated:
@@ -770,7 +797,7 @@ class ServingEngine:
         Returns False when transient faults spent this rung's retry budget
         — the ladder escalated to the K=1 mixed rung and the caller falls
         through to it for this iteration."""
-        s = self.sched
+        s, ph = self.sched, self._phases
         if not self._retry_transients():
             self._escalate()
             return False
@@ -793,7 +820,7 @@ class ServingEngine:
                     iteration=self.iteration, slots=n, span_k=int(k),
                 )
         traces_before = self.trace_counts["rolled_step"]
-        t0 = time.perf_counter()
+        t0 = ph.to("engine.launch")
         if self.injector is not None:
             sp = self.injector.spike_s(self.iteration)
             if sp:
@@ -804,10 +831,10 @@ class ServingEngine:
             jnp.asarray(steps, np.int32), jnp.int32(k),
             jnp.asarray(poison),
         )
+        ph.to("engine.sync")
         out = np.asarray(out)  # block for an honest span time
-        t1 = time.perf_counter()
+        t1 = ph.to("engine.harvest")
         dt_ms = (t1 - t0) * 1e3
-        self.stats["device_s"] += dt_ms / 1e3
         self._note_healthy()
         adv = int(steps.max())  # device iterations actually executed
         calibrated = self.trace_counts["rolled_step"] == traces_before and adv > 0
@@ -1045,7 +1072,8 @@ class ServingEngine:
         fin = self.sched.finished
         shed = self.sched.shed
         spec_on = self.draft is not None and self.spec_len > 0
-        wall = self.stats.get("wall_s") or self.stats["device_s"] or None
+        device_s = self.stats["launch_s"] + self.stats["sync_s"]
+        wall = self.stats.get("wall_s") or device_s or None
 
         def _dispositions(rs: list) -> dict:
             out = {"shed": 0, "expired": 0, "cancelled": 0, "poisoned": 0}
@@ -1054,17 +1082,21 @@ class ServingEngine:
                     out[r.status] += 1
             return out
 
+        def _latencies(rs: list) -> dict:
+            # as the client sees them, from submission (queue wait
+            # included); prefill_s is admission -> first token
+            return {
+                "latency_s": _since(rs, "t_submit", "t_done"),
+                "ttft_s": _since(rs, "t_submit", "t_first"),
+                "prefill_s": _since(rs, "t_admit", "t_first"),
+            }
+
         tenants = {}
         for t, rs in sorted(_by_tenant(fin + shed).items()):
             t_fin = [r for r in rs if r.status == "ok"]
             tenants[t] = {
                 "finished": len(t_fin),
-                "latency_s": _percentiles(
-                    [r.t_done - r.t_admit for r in t_fin if r.t_done and r.t_admit]
-                ),
-                "ttft_s": _percentiles(
-                    [r.t_first - r.t_admit for r in t_fin if r.t_first and r.t_admit]
-                ),
+                **_latencies(t_fin),
                 **_dispositions(rs),
             }
         return {
@@ -1077,7 +1109,7 @@ class ServingEngine:
             "traces": dict(self.trace_counts),
             "fused_attention": self.fused,
             "wall_s": self.stats.get("wall_s"),
-            "device_s": self.stats["device_s"],
+            "device_s": device_s,
             "step_ms": self.sched.step_ms,
             "tok_per_s": (
                 self.stats["generated_tokens"] / wall if wall else None
@@ -1093,12 +1125,7 @@ class ServingEngine:
                     else None
                 ),
             },
-            "latency_s": _percentiles(
-                [r.t_done - r.t_admit for r in fin if r.t_done and r.t_admit]
-            ),
-            "ttft_s": _percentiles(
-                [r.t_first - r.t_admit for r in fin if r.t_first and r.t_admit]
-            ),
+            **_latencies(fin),
             "tenants": tenants,
             "requests": {
                 "finished": len(fin),

@@ -28,6 +28,7 @@ from repro.core.hardware import TPU_V5E
 from repro.core.plan import derive_plan, derive_serve_plan
 from repro.obs import (
     PID_ENGINE,
+    TID_PHASES,
     Observability,
     Tracer,
     parse_prometheus_text,
@@ -37,7 +38,7 @@ from repro.obs import (
 from repro.obs.calibrate import DriftMeter, step_time_model
 from repro.obs.metrics import MetricsRegistry
 from repro.serve import ServingEngine
-from repro.serve.scheduler import random_stream
+from repro.serve.scheduler import Request, random_stream
 
 MESH1 = {"data": 1, "model": 1}
 
@@ -228,6 +229,57 @@ def test_default_obs_keeps_tracing_off(obs_run):
     assert plain.obs.tracer.enabled is False
     assert len(validate_chrome_trace(plain.obs.tracer.chrome_trace())) == 0
     assert not plain.obs.drift.empty  # drift still accumulated
+
+
+PHASES = ("engine.admit", "engine.pack", "engine.launch", "engine.sync",
+          "engine.harvest")
+
+
+def test_step_phases_tile_the_call(key):
+    """Every step() that dispatches is tiled by the five phases, in order,
+    with no gap or overlap (one clock read per boundary), inside the call;
+    the phase counters hold the phases' seconds, the dispatch wall time is
+    launch + sync, and reset_stats() zeroes them."""
+    import time
+
+    cfg = get_config("smollm-135m").reduced()
+    plan = derive_plan(cfg, MESH1, batch=2, seq_len=16, training=False)
+    from repro.models.params import init_params
+
+    params = init_params(key, cfg, plan, dtype=jnp.float32)
+    serve = derive_serve_plan(
+        cfg, MESH1, max_seq_len=64, decode_batch=2, block_size=8,
+        prefill_chunk=8, mixed_slab_width=8, rolled_steps=4,
+    )
+    obs = Observability(tracing=True)
+    engine = ServingEngine(params, cfg, plan, serve, obs=obs)
+    tr = obs.tracer
+    engine.submit(Request(rid="a", prompt=list(range(1, 12)), max_new_tokens=9))
+    seen = 0
+    totals = dict.fromkeys(PHASES, 0.0)
+    while not engine.sched.idle:
+        t_a = time.perf_counter()
+        engine.step()
+        t_b = time.perf_counter()
+        events = [e for e in validate_chrome_trace(tr.chrome_trace())
+                  if e["pid"] == PID_ENGINE and e["tid"] == TID_PHASES]
+        new, seen = events[seen:], len(events)
+        assert [e["name"] for e in new] == list(PHASES)
+        assert tr._ts(t_a) <= new[0]["ts"]
+        assert new[-1]["ts"] + new[-1]["dur"] <= tr._ts(t_b)
+        for a, b in zip(new, new[1:]):
+            assert b["ts"] == pytest.approx(a["ts"] + a["dur"], abs=1e-3)  # 1 ns
+        for e in new:
+            totals[e["name"]] += e["dur"] / 1e6
+    assert engine.stats["rolled_dispatches"] > 0 and engine.stats["steps"] > 1
+    for name, total in totals.items():
+        assert engine.stats[name.split(".")[1] + "_s"] == pytest.approx(total, rel=1e-6)
+    dispatch = [e for e in validate_chrome_trace(tr.chrome_trace())
+                if e["name"] in ("step", "rolled_step")]
+    assert engine.summary()["device_s"] == pytest.approx(
+        sum(e["dur"] for e in dispatch) / 1e6, rel=1e-6)
+    engine.reset_stats()
+    assert all(engine.stats[p.split(".")[1] + "_s"] == 0.0 for p in PHASES)
 
 
 def test_fault_events_carry_determinism_key(key):

@@ -339,6 +339,16 @@ def test_summary_safe_at_zero_and_one_sample(key):
     assert lat["n"] == 1  # a 1-sample p99 must be recognizable as such
     assert lat["p50"] == lat["p90"] == lat["p99"] == lat["mean"]
     assert s["step_ms"] is None or s["step_ms"] > 0
+    # latency and TTFT as the client sees them, from submission; admission
+    # to first token under its own name; the histograms agree
+    r = engine.sched.finished[0]
+    assert lat["p50"] == pytest.approx(r.t_done - r.t_submit)
+    assert s["ttft_s"]["p50"] == pytest.approx(r.t_first - r.t_submit)
+    assert s["prefill_s"]["p50"] == pytest.approx(r.t_first - r.t_admit)
+    assert s["tenants"][r.tenant]["ttft_s"] == s["ttft_s"]
+    m = engine.obs.metrics.snapshot()
+    for name, t in (("serve_ttft_ms", r.t_first), ("serve_latency_ms", r.t_done)):
+        assert m[name]["samples"][0]["sum"] == pytest.approx((t - r.t_submit) * 1e3)
 
 
 # ----------------------------------------------------------- plan-driven
