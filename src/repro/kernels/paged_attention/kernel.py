@@ -13,8 +13,9 @@ masking and sliding-window wraparound.  No dense gathered cache ever exists
 in HBM.
 
 Layouts (ops.py does the model-layout shuffle):
-  q       (B, KH, G*W, D)   row r of slot b = query i = r % W of group
-                            g = r // W, at absolute position lens[b] + i
+  q       (B, KH, G*W, D)   row r of slot b = query i = r // G of group
+                            g = r % G, at absolute position lens[b] + i;
+                            a slot's live rows are its first G * q_lens[b]
   pools   (N, bs, KH, D)    k/v pages; 16-bit float pools arrive bitcast
                             to int16 (bits are bits for a DMA, and the
                             interpreter's bf16 copy path is pathological)
@@ -31,6 +32,14 @@ online softmax across the tile sweep, all KV heads batched in one program.
 Tiles entirely past a slot's high-water mark (or entirely below its
 attention window) skip both the DMA and the compute — per-slot work is
 proportional to the slot's live context, not to ``cache_len``.
+
+Within a live tile the score, softmax and accumulate work follows the
+slot's live rows, not the whole G*W (:func:`row_sizes`): one update of the
+slot's first Rs rows (G rounded up to the 8-row sublane tile) when the
+live rows fit them — every decode slot and every W = 1 step — else of the
+first multiple of 128 rows that holds them, up to all G*W for a full
+prefill chunk.  Rows past the update keep their zero carry and come back
+as exact zeros.
 """
 from __future__ import annotations
 
@@ -39,6 +48,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,6 +72,25 @@ NAME = "paged_attention"
 
 def _padded(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def row_sizes(G: int, W: int) -> tuple[int, ...]:
+    """Query-row counts a live tile's update can cover, each from a slot's
+    first row: G rounded up to the 8-row sublane tile (a decode slot), then
+    multiples of 128 rows (of a larger step where G * W > 1024, so there are
+    at most 8) up to all G * W.  A slot's update covers the smallest that
+    holds its G * q_len live rows."""
+    GW = G * W
+    step = 128 * -(-GW // 1024)
+    return tuple(sorted({min(_padded(G, 8), GW), *range(step, GW, step), GW}))
+
+
+def rows_computed(G: int, W: int, q_lens) -> np.ndarray:
+    """Query rows per KV head the kernel computes on each live tile of slots
+    with ``q_lens`` live queries (0 for an idle slot), by :func:`row_sizes`."""
+    sizes = np.asarray(row_sizes(G, W))
+    n = G * np.asarray(q_lens, np.int64)
+    return np.where(n == 0, 0, sizes[np.searchsorted(sizes, n)])
 
 
 def vmem_bytes(
@@ -92,7 +121,7 @@ def _paged_kernel(
     o_ref,
     # scratch
     kt, vt, kst, vst, m_ref, l_ref, acc_ref, sems,
-    *, W: int, bs: int, ppt: int, nt: int, window: int, scale: float,
+    *, G: int, W: int, bs: int, ppt: int, nt: int, window: int, scale: float,
     quantized: bool,
 ):
     b = pl.program_id(0)
@@ -149,7 +178,7 @@ def _paged_kernel(
 
         jax.lax.fori_loop(0, ppt, fetch, 0)
 
-        KH, GW, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+        KH, D = q_ref.shape[1], q_ref.shape[3]
         T = ppt * bs
 
         def pages(tile, st=None):  # (ppt, bs, KH, D) -> (T, KH, D)
@@ -175,36 +204,50 @@ def _paged_kernel(
 
         # Pages stay in their DMA'd (token, head, d) layout; the head dim
         # rides as a dot_general batch dim so no in-VMEM transpose is paid.
-        q = q_ref[0].astype(k.dtype)  # (KH, GW, D)
-        s = (
-            jax.lax.dot_general(
-                q, k, (((2,), (2,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32,
+        def update(R):
+            """Online-softmax update of the slot's first R query rows."""
+            q = q_ref[0, :, :R, :].astype(k.dtype)  # (KH, R, D)
+            s = (
+                jax.lax.dot_general(
+                    q, k, (((2,), (2,)), ((0,), (1,))),
+                    preferred_element_type=jnp.float32,
+                )
+                * scale
+            )  # (KH, R, T)
+
+            # row r holds query r // G at position base + r // G; with
+            # d = G * (key position - base), the key is causal for row r
+            # iff d <= r and inside the window iff r < d + G * window, so
+            # the mask needs no integer division
+            r = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+            d = G * (tile_lo - base + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1))
+            valid = (d <= r) & (r < G * q_len)
+            if window > 0:
+                valid &= r < d + G * window
+            valid = valid[None]  # broadcast over KH
+            s = jnp.where(valid, s, NEG_INF)
+
+            m_prev = m_ref[:, :R]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            # `where` (not bare exp) so fully-masked rows contribute exactly
+            # 0 while m is still NEG_INF — exp(NEG_INF - NEG_INF) would be 1.
+            p_ = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:, :R] = l_ref[:, :R] * corr + p_.sum(axis=-1)
+            acc_ref[:, :R, :] = acc_ref[:, :R, :] * corr[..., None] + (
+                jax.lax.dot_general(
+                    p_.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
+                    preferred_element_type=jnp.float32,
+                )
             )
-            * scale
-        )  # (KH, GW, T)
+            m_ref[:, :R] = m_new
 
-        qi = jax.lax.broadcasted_iota(jnp.int32, (GW, T), 0) % W
-        pos = base + qi  # per-row absolute position
-        j = tile_lo + jax.lax.broadcasted_iota(jnp.int32, (GW, T), 1)
-        valid = (j <= pos) & (qi < q_len)
-        if window > 0:
-            valid &= (pos - j) < window
-        valid = valid[None]  # broadcast over KH
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        # `where` (not bare exp) so fully-masked rows contribute exactly 0
-        # while m is still NEG_INF — exp(NEG_INF - NEG_INF) would be 1.
-        p_ = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p_.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jax.lax.dot_general(
-            p_.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        # one update of the smallest row count that holds the live rows: a
+        # static slice, so every size is its own branch
+        n_live, lo = G * q_len, 0
+        for R in row_sizes(G, W):
+            pl.when((n_live > lo) & (n_live <= R))(functools.partial(update, R))
+            lo = R
 
     @pl.when(t == nt - 1)
     def _done():
@@ -249,7 +292,7 @@ def paged_attention_call(
 
     kernel = functools.partial(
         _paged_kernel,
-        W=slab, bs=bs, ppt=ppt, nt=nt, window=window, scale=scale,
+        G=GW // slab, W=slab, bs=bs, ppt=ppt, nt=nt, window=window, scale=scale,
         quantized=quantized,
     )
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)

@@ -40,11 +40,11 @@ def paged_attention(
     G = H // KH
     MB = table.shape[1]
     ppt = largest_divisor_of(MB, pages_per_tile or MB)
-    # (B, W, H, D) -> (B, KH, G*W, D): q head h = kh*G + g consumes kv head
-    # kh (same GQA map as models/layers + the flash kernel); row r = g*W + i.
-    qr = (
-        q.reshape(B, W, KH, G, D).transpose(0, 2, 3, 1, 4).reshape(B, KH, G * W, D)
-    )
+    # (B, W, H, D) -> (B, KH, W*G, D): q head h = kh*G + g consumes kv head
+    # kh (same GQA map as models/layers + the flash kernel); row r = i*G + g,
+    # so query i's G heads sit side by side and a slot's live rows are its
+    # first G * q_lens[b] (the kernel computes only the rows that hold them).
+    qr = q.reshape(B, W, KH, G, D).transpose(0, 2, 1, 3, 4).reshape(B, KH, W * G, D)
     out = paged_attention_call(
         qr,
         entry["k"],
@@ -59,4 +59,4 @@ def paged_attention(
         pages_per_tile=ppt,
         window=window,
     )
-    return out.reshape(B, KH, G, W, D).transpose(0, 3, 1, 2, 4).reshape(B, W, H, D)
+    return out.reshape(B, KH, W, G, D).transpose(0, 2, 1, 3, 4).reshape(B, W, H, D)

@@ -35,6 +35,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.hardware import DEFAULT_HARDWARE, HardwareSpec, resolve_hardware
 from repro.core.plan import ExecutionPlan, ServePlan, serve_feasible
+from repro.kernels.paged_attention.kernel import rows_computed
 from repro.models.cache import (
     cache_from_prefill,
     init_paged_cache,
@@ -447,6 +448,9 @@ class ServingEngine:
             # the call, and launch_s + sync_s is the dispatches' wall time
             "admit_s": 0.0, "pack_s": 0.0, "launch_s": 0.0, "sync_s": 0.0,
             "harvest_s": 0.0,
+            # query rows per KV head and layer the paged kernel computes on
+            # a live tile, and those carrying a token (``_count_attn_rows``)
+            "attn_rows": 0, "attn_live_rows": 0,
         }
         self._phases = Phases(self.obs.tracer, self.stats)
         # degradation ladder: 0 = rolled-K spans, 1 = K=1 mixed step,
@@ -785,6 +789,8 @@ class ServingEngine:
             self.stats["occupancy_sum"] += (
                 int((kinds > 0).sum()) / self.serve.decode_batch
             )
+            if self.fused and trace_key == "step":
+                self._count_attn_rows(ka, self.serve.mixed_slab_width)
         self.iteration += 1
 
     def _rolled_dispatch(self, k: int, steps: np.ndarray) -> bool:
@@ -869,8 +875,20 @@ class ServingEngine:
         # same unit as the K=1 path: live-slot fraction summed per device
         # iteration (slot b is live for its first steps[b] iterations)
         self.stats["occupancy_sum"] += float(steps.sum()) / self.serve.decode_batch
+        if self.fused:
+            self._count_attn_rows(np.asarray(steps) > 0, 1, reps=steps)
         self.iteration += adv
         return True
+
+    def _count_attn_rows(self, q_lens, width: int, reps=1) -> None:
+        """Add a dispatch's rows to ``attn_rows`` (rows per KV head and layer
+        the paged kernel computes on a live tile, by the kernel's own rule)
+        and ``attn_live_rows`` (G per live query): slots with ``q_lens``
+        live queries in a slab of ``width``, each ``reps`` times."""
+        G = self.cfg.n_heads // self.cfg.n_kv_heads
+        q_lens = np.asarray(q_lens, np.int64)
+        self.stats["attn_rows"] += int((rows_computed(G, width, q_lens) * reps).sum())
+        self.stats["attn_live_rows"] += int((G * q_lens * reps).sum())
 
     def run(self, requests=(), max_iterations: int = 100_000) -> dict:
         """Drive the stream to completion; returns {rid: generated tokens}

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.plan import int8_scale_lanes
+from repro.kernels.paged_attention.kernel import row_sizes, rows_computed
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
@@ -62,13 +63,27 @@ CASES = [
     (2, 4, 4, 2, 32, 4, 16, "int8", 0, 16),  # int8 in-kernel dequant
     (3, 4, 2, 2, 32, 4, 16, "float32", 12, 1),  # SWA, page-at-a-time
     (2, 1, 4, 1, 64, 8, 8, "int8", 20, 2),  # SWA decode past the window
+    # live-row updates, with q_lens per slot (slot 0 stays empty):
+    # G = 2, G*W = 384 (row sizes 8, 128, 256, 384): decode and full-chunk
+    # slots mixed
+    (4, 192, 4, 2, 32, 8, 40, "float32", 0, 4, (0, 1, 192, 1)),
+    # G = 8 on one KV head (MQA): decode rows fill the 8-row update
+    (3, 48, 8, 1, 32, 8, 16, "int8", 0, 2, (0, 48, 1)),
+    # G = 1: G*q_len at the small size 8, at 8 + 1 and at 128 + 1
+    (4, 256, 2, 2, 32, 8, 48, "float32", 0, 8, (0, 8, 9, 129)),
+    # G = 2 with SWA: G*q_len at 8, at 128 + 2, and the full 256 rows
+    (4, 128, 4, 2, 32, 8, 32, "bfloat16", 24, 2, (0, 4, 65, 128)),
+    # G = 16 on one KV head: sizes G and G*W = 128
+    (3, 8, 16, 1, 32, 8, 8, "int8", 0, 2, (0, 1, 8)),
 ]
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_kernel_matches_ref(case):
-    B, W, H, KH, D, bs, MB, kv_dtype, window, ppt = case
+    B, W, H, KH, D, bs, MB, kv_dtype, window, ppt, *fixed = case
     q, entry, table, lens, q_lens = _mk_case(B, W, H, KH, D, bs, MB, kv_dtype)
+    if fixed:
+        q_lens = jnp.asarray(fixed[0], jnp.int32)
     got = np.asarray(
         paged_attention(
             q, entry, table, lens, q_lens,
@@ -84,8 +99,30 @@ def test_kernel_matches_ref(case):
     )
     tol = 3e-2 if kv_dtype == "bfloat16" else 2e-5
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-    # the empty slot (trash table, q_lens 0) must come back exactly zero
+    # the empty slot (trash table, q_lens 0) must come back exactly zero,
+    # and so must every row past a slot's q_lens
     np.testing.assert_array_equal(got[0], np.zeros_like(got[0]))
+    dead = np.arange(W)[None] >= np.asarray(q_lens)[:, None]  # (B, W)
+    np.testing.assert_array_equal(got[dead], np.zeros_like(got[dead]))
+
+
+@pytest.mark.parametrize(
+    "G, W, want",
+    [
+        (2, 256, (0, 8, 512)),  # sizes 8, 128, ..., 512
+        (16, 256, (0, 16, 4096)),  # sizes 16, 512, ..., 4096
+        (2, 1, (0, 2, 2)),  # a W = 1 step: the one size G * W
+        (16, 1, (0, 16, 16)),
+    ],
+)
+def test_row_sizes_rule(G, W, want):
+    """Rows per KV head computed on a live tile for q_len 0, 1 and W: an
+    idle slot none, a decode slot the small size, a full chunk all G*W;
+    at most 8 sizes above the small one."""
+    sizes = row_sizes(G, W)
+    assert list(sizes) == sorted(set(sizes)) and sizes[-1] == G * W
+    assert len(sizes) <= 9
+    assert tuple(rows_computed(G, W, [0, 1, W])) == want
 
 
 def test_tile_sweep_invariance():

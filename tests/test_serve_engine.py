@@ -253,6 +253,40 @@ def test_rolled_loop_parity_and_span_accounting(key):
     # the clock identically, but the rolled engine dispatched fewer times
     assert rolled.iteration == k1.iteration
     assert rolled.stats["rolled_steps"] > rolled.stats["rolled_dispatches"]
+    # a rolled span counts the kernel's live rows as its K=1 steps would
+    assert rolled.stats["attn_live_rows"] == k1.stats["attn_live_rows"] > 0
+
+
+def test_attn_row_counters_follow_the_packed_slab(key):
+    """``attn_live_rows`` is G x the live rows each step packed and
+    ``attn_rows`` the kernel's row rule over them, at least as many; a
+    step with decode slots computes fewer rows than the whole slab."""
+    from repro.kernels.paged_attention.kernel import rows_computed
+
+    cfg, plan, serve, params = _setup(key, rolled_steps=1)
+    G, W = cfg.n_heads // cfg.n_kv_heads, serve.mixed_slab_width
+    engine = ServingEngine(params, cfg, plan, serve)
+    packed = []
+    step = engine._step
+    engine._step = lambda *a: (packed.append(np.asarray(a[5])), step(*a))[1]
+    rng = np.random.default_rng(3)
+    for i, n in enumerate((5, 20, 3, 17)):
+        engine.submit(Request(f"c{i}", list(rng.integers(0, cfg.vocab_size, n)), 6))
+    mixed = 0
+    while not engine.sched.idle:
+        before, n = dict(engine.stats), len(packed)
+        engine.step()
+        kinds = packed[-1] if len(packed) > n else np.zeros(1, int)
+        live = G * int(kinds.sum())
+        got = engine.stats["attn_live_rows"] - before["attn_live_rows"]
+        rows = engine.stats["attn_rows"] - before["attn_rows"]
+        assert got == live
+        assert rows == int(rows_computed(G, W, kinds).sum()) >= live
+        if (kinds == 1).any():
+            assert rows < G * W * int((kinds > 0).sum())
+        mixed += bool((kinds == 1).any() and (kinds > 1).any())
+    assert mixed  # some step carried decode and prefill slots together
+    assert_traces_bounded(engine.trace_counts)
 
 
 def test_plan_rolled_event_horizon_and_reservation(key):
