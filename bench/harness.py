@@ -132,6 +132,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, reg=None,
     reg = reg or R.Registry()
     cell = reg.cell(workload)
     c = reg.config(cell["config"])
+    arch = reg.arch(c)
     traffic = reg.traffic(cell["traffic"])
     check = R.load_json(reg.base / "checks" / f"{workload}.json")
     if devices is None:
@@ -141,7 +142,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, reg=None,
     counter = CompileCounter()
 
     engine, cfg, plan, serve = model.build(
-        c, seed, cfg=program_cfg, serve_overrides=serve_overrides)
+        arch, c, seed, cfg=program_cfg, serve_overrides=serve_overrides)
     log(serve.describe())
     if fault is not None:
         fault(engine)
@@ -150,7 +151,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, reg=None,
     gen = reg.generator(traffic["generator"]).Generator(traffic, seed, c["vocab_size"], seconds)
 
     tracer = Tracer(workload, seed, traffic) if trace else None
-    win = window.Window(engine, gen, c, seconds, drain_s=traffic.get("drain_s", 0.0),
+    win = window.Window(engine, gen, arch, c, seconds, drain_s=traffic.get("drain_s", 0.0),
                         annotate=tracer.annotate if tracer else None,
                         on_step=tracer.on_step if tracer else None)
     t_setup = time.time()
@@ -171,9 +172,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *, reg=None,
     model.release(engine)
     del engine
     hi, lo = W.seed_words(seed)
-    wts = jax.jit(lambda a, b: W.draw(c, a, b))(np.uint32(hi), np.uint32(lo))
+    wts = jax.jit(lambda a, b: W.draw(arch, c, a, b))(np.uint32(hi), np.uint32(lo))
     t_ref = time.time()
-    got = reference.gaps(c, wts, sample_io, int(c["max_seq_len"]), control=control)
+    got = reference.gaps(arch, c, wts, sample_io, int(c["max_seq_len"]), control=control)
     log(f"reference over {len(sample)} requests, {got['tokens']} served tokens: "
         f"{time.time() - t_ref:.1f} s")
     del wts

@@ -1,6 +1,7 @@
-"""Plain float32 forward pass of the configurations the benchmark serves,
-written from the published description (RMSNorm, RoPE, grouped-query
-attention, SwiGLU, tied output head) and from nothing of the program.
+"""Plain float32 reference of the configurations the benchmark serves: the
+final logits, formed from the hidden states that the configuration's
+architecture module computes (``hidden`` in ``bench/archs/<name>.py``,
+written from the published description and from nothing of the program).
 
 Every matmul runs at ``HIGHEST`` precision, so on a TPU it is float32 and
 not the chip's default of bfloat16 operands.  ``quantized=True`` is the
@@ -12,12 +13,11 @@ stated in each configuration file; this pass follows the file."""
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -36,67 +36,16 @@ def _mm(x, w, quantized):
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rms(x, offset, eps):
-    g = 1.0 + offset
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
-
-
-def _rope(x, pos, theta, fraction):
-    """Rotate the first ``fraction`` of each head by position, pairing
-    dimension i with i + rot/2 (the published models' layout)."""
-    Dh = x.shape[-1]
-    rot = int(Dh * fraction)
-    freqs = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = pos[:, None].astype(jnp.float32) * freqs[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2, rest = x[..., : rot // 2], x[..., rot // 2 : rot], x[..., rot:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def hidden(c: dict, w: dict, tokens, quantized: bool = False):
-    """Final hidden states (S, d) of one sequence ``tokens`` (S,)."""
-    m = W.dims(c)
-    H, KV, Dh = m["H"], m["KV"], m["Dh"]
-    eps, theta = float(c["rms_norm_eps"]), float(c["rope_theta"])
-    frac = float(c.get("partial_rotary_factor", 1.0))
-    S = tokens.shape[0]
-    pos = jnp.arange(S)
-    causal = pos[None, :] <= pos[:, None]
-    x = w["embed"][tokens]
-
-    def layer(x, lw):
-        h = _rms(x, lw["attn_norm"], eps)
-        q = _mm(h, lw["wq"], quantized).reshape(S, H, Dh)
-        k = _mm(h, lw["wk"], quantized).reshape(S, KV, Dh)
-        v = _mm(h, lw["wv"], quantized).reshape(S, KV, Dh)
-        if m["qk_norm"]:
-            q, k = _rms(q, lw["q_norm"], eps), _rms(k, lw["k_norm"], eps)
-        q, k = _rope(q, pos, theta, frac), _rope(k, pos, theta, frac)
-        if quantized:
-            k, v = _q8(k, -1), _q8(v, -1)
-        k = jnp.repeat(k, H // KV, axis=1)  # query head i reads kv head i // (H/KV)
-        v = jnp.repeat(v, H // KV, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / np.sqrt(Dh)
-        p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", p, v, precision=HI).reshape(S, H * Dh)
-        x = x + _mm(o, lw["wo"], quantized)
-        h = _rms(x, lw["ffn_norm"], eps)
-        f = jax.nn.silu(_mm(h, lw["w_gate"], quantized)) * _mm(h, lw["w_up"], quantized)
-        return x + _mm(f, lw["w_down"], quantized), None
-
-    per_layer = {k: v for k, v in w.items() if k not in ("embed", "final_norm")}
-    x, _ = jax.lax.scan(layer, x, per_layer)
-    return _rms(x, w["final_norm"], eps)
-
-
-@functools.partial(jax.jit, static_argnames=("c_key", "quantized", "block"))
-def _readings(w, tokens, served, c_key, quantized=False, block=256):
+@functools.partial(jax.jit, static_argnames=("arch", "c_key", "quantized", "block"))
+def _readings(w, tokens, served, arch, c_key, quantized=False, block=256):
     """Per position: the reference's best logit, its logit of the served
     token, and (control) its logit of the token the quantized pass puts
-    first.  Logits are formed ``block`` positions at a time."""
-    c = dict(c_key)
-    x = hidden(c, w, tokens)
-    xq = hidden(c, w, tokens, quantized=True) if quantized else x
+    first.  Logits are formed ``block`` positions at a time from the hidden
+    states of architecture module ``arch``; ``c_key`` is the whole
+    configuration file, as ``_hashable`` gives it."""
+    c = json.loads(c_key)
+    x = arch.hidden(c, w, tokens)
+    xq = arch.hidden(c, w, tokens, quantized=True) if quantized else x
     head = w["embed"].T
     S = tokens.shape[0]
 
@@ -118,22 +67,21 @@ def _readings(w, tokens, served, c_key, quantized=False, block=256):
     return best.reshape(S), got.reshape(S), ctl.reshape(S)
 
 
-def _hashable(c: dict) -> tuple:
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "intermediate_size", "vocab_size", "qk_norm", "rms_norm_eps",
-            "rope_theta", "partial_rotary_factor")
-    return tuple((k, c[k]) for k in keys if k in c)
+def _hashable(c: dict) -> str:
+    """The whole configuration file, every key and nested value of it, as
+    a static argument of ``_readings``."""
+    return json.dumps(c, sort_keys=True)
 
 
 BUCKET = 512
 
 
-def gaps(c: dict, w: dict, sample: list[tuple[list[int], list[int]]], length: int,
+def gaps(arch, c: dict, w: dict, sample: list[tuple[list[int], list[int]]], length: int,
          control: bool = False) -> dict:
     """The gap by which a served token's reference logit lies below the
-    reference's best, over ``sample`` = [(prompt, served tokens)], each
-    sequence padded to the next multiple of 512 (at most ``length``): the
-    widest (``max_logit_gap``) and the mean over every compared token
+    reference's best (configuration ``c``, architecture module ``arch``),
+    over ``sample`` = [(prompt, served tokens)], each sequence padded to
+    the next multiple of 512 (at most ``length``): the widest (``max_logit_gap``) and the mean over every compared token
     (``mean_logit_gap``).  With ``control`` the same two of the token the
     int8 pass puts first at the same positions (``control_...``)."""
     worst, worst_ctl, total, total_ctl, n = 0.0, 0.0, 0.0, 0.0, 0
@@ -150,7 +98,8 @@ def gaps(c: dict, w: dict, sample: list[tuple[list[int], list[int]]], length: in
         target[first : first + len(served)] = served
         best, got, ctl = (
             np.asarray(a) for a in _readings(w, jnp.asarray(tokens), jnp.asarray(target),
-                                             c_key=_hashable(c), quantized=control, block=block)
+                                             arch=arch, c_key=_hashable(c), quantized=control,
+                                             block=block)
         )
         sl = slice(first, first + len(served))
         gap, gap_ctl = best[sl] - got[sl], best[sl] - ctl[sl]

@@ -1,8 +1,9 @@
 """Finds the benchmark's parts by name: a cell in ``BENCHMARK.json``, its
-configuration and traffic files, a generator kind and a metric reader.
+configuration and traffic files, a configuration's architecture module, a
+generator kind and a metric reader.
 
-A later cell, traffic mix, generator or metric is added as a file; nothing
-here lists them."""
+A later cell, traffic mix, architecture, generator or metric is added as a
+file; nothing here lists them."""
 from __future__ import annotations
 
 import importlib.util
@@ -56,6 +57,12 @@ class Registry:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod
+
+    def arch(self, c: dict) -> ModuleType:
+        """The architecture module configuration file ``c`` names under
+        ``"arch_module"``: ``archs/<name>.py``, ``archs/dense.py`` where it
+        names none."""
+        return self.module("archs", c.get("arch_module", "dense"))
 
     def generator(self, kind: str) -> ModuleType:
         return self.module("generators", kind)

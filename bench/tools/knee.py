@@ -32,16 +32,17 @@ def main():
     reg = registry.Registry()
     cell = reg.cell(a.workload)
     c = reg.config(cell["config"])
+    arch = reg.arch(c)
     traffic = reg.traffic(cell["traffic"])
     harness.require_chips(int(cell["chips"]))
     harness.enable_cache()
-    engine, cfg, plan, serve = model.build(c, a.seed)
+    engine, cfg, plan, serve = model.build(arch, c, a.seed)
     harness.warm_up(engine)
     rows = []
     for rate in [float(r) for r in a.rates.split(",")]:
         t = dict(traffic, rate_per_s=rate)
         gen = reg.generator(t["generator"]).Generator(t, a.seed, c["vocab_size"], a.seconds)
-        w = window.Window(engine, gen, c, a.seconds, drain_s=t.get("drain_s", 60.0)).run()
+        w = window.Window(engine, gen, arch, c, a.seconds, drain_s=t.get("drain_s", 60.0)).run()
         queued = sum(1 for r in w.recs if r.counted and r.t_admit is None)
         ttft = readers.ttft_ms(w)
         counted = [r for r in w.recs if r.counted]

@@ -47,10 +47,12 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    c = registry.Registry().config(a.config)
+    reg = registry.Registry()
+    c = reg.config(a.config)
     if a.layers:
         c = dict(c, num_hidden_layers=a.layers)
-    cfg = model.program_config(c)
+    arch = reg.arch(c)
+    cfg = model.program_config(arch, c)
     mesh = jax.sharding.Mesh([[topo.devices[0]]], ("data", "model"))
     plan, serve = model.plans(c, cfg, mesh, TPU_V5E)
     out = {"config": a.config, "layers": cfg.n_layers, "params_b": cfg.param_count() / 1e9,
@@ -58,8 +60,10 @@ def main():
     place = lambda t: jax.tree.map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), t)
     u32 = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one)
-    shape_tree = jax.eval_shape(lambda: model.to_program_tree(W.draw(c, 0, 0), cfg, plan))
-    wp = model.weights_program(c, cfg, plan, out_shardings=jax.tree.map(lambda _: one, shape_tree))
+    shape_tree = jax.eval_shape(
+        lambda: arch.to_program_tree(W.draw(arch, c, 0, 0), cfg, plan))
+    wp = model.weights_program(arch, c, cfg, plan,
+                               out_shardings=jax.tree.map(lambda _: one, shape_tree))
     out["weights_program"] = mem(wp.lower(u32, u32).compile())
     params = place(shape_tree)
     pools = place(jax.eval_shape(lambda: init_paged_cache(cfg, plan, serve)))
@@ -73,11 +77,11 @@ def main():
     out["step"]["fits_gb"] = (TPU_V5E.hbm_bytes - 2**28) / 1e9
     if not a.skip_reference:
         L = int(c["max_seq_len"])
-        wts = place(jax.eval_shape(lambda: W.draw(c, 0, 0)))
+        wts = place(jax.eval_shape(lambda: W.draw(arch, c, 0, 0)))
         for ctl in (False, True):
             fn = reference._readings.lower(
                 wts, S((L,), jnp.int32), S((L,), jnp.int32),
-                c_key=reference._hashable(c), quantized=ctl, block=256)
+                arch=arch, c_key=reference._hashable(c), quantized=ctl, block=256)
             out["reference_control" if ctl else "reference"] = mem(fn.compile())
     print(json.dumps(out, indent=1), flush=True)
 

@@ -45,12 +45,12 @@ class Window:
     ``annotate(name)`` returns a context manager that marks host spans in the
     profiler's trace (``jax.profiler.TraceAnnotation`` in a traced run)."""
 
-    def __init__(self, engine, gen, c: dict, seconds: float, *, drain_s: float = 0.0,
+    def __init__(self, engine, gen, arch, c: dict, seconds: float, *, drain_s: float = 0.0,
                  annotate=None, on_step=None):
         from repro.serve.scheduler import Request
 
         self.Request = Request
-        self.engine, self.gen, self.c = engine, gen, c
+        self.engine, self.gen, self.arch, self.c = engine, gen, arch, c
         self.seconds, self.drain_s = float(seconds), float(drain_s)
         self.annotate = annotate or (lambda name: nullcontext())
         self.on_step = on_step  # called with the step count before each step (tracing)
@@ -97,7 +97,7 @@ class Window:
             if req.state == "done":
                 rec.status = req.status
                 del self.live[rid]
-        return flops.step_work(self.c, slots, self.kv_dtype)
+        return flops.step_work(self.arch, self.c, slots, self.kv_dtype)
 
     def _pending_first(self) -> bool:
         return any(r.counted and not r.stamps and r.status == "pending" for r in self.recs)

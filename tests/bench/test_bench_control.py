@@ -17,6 +17,8 @@ from bench import weights as W
 
 import tinybench
 
+dense = registry.Registry().arch({})
+
 # Wide and deep enough that, as at the published widths, the layers and not
 # the tied input embedding set the logits, so near ties occur.
 C = {"num_hidden_layers": 4, "hidden_size": 512, "num_attention_heads": 8,
@@ -27,9 +29,9 @@ DATA = pathlib.Path(__file__).parent / "data"
 
 
 def test_control_reads_wider_gaps_than_the_exact_program():
-    w = jax.jit(lambda: W.draw(C, 0, 77))()
+    w = jax.jit(lambda: W.draw(dense, C, 0, 77))()
     greedy = jax.jit(lambda toks, p: jnp.argmax(
-        jnp.matmul(reference.hidden(C, w, toks)[p], w["embed"].T, precision="highest")))
+        jnp.matmul(dense.hidden(C, w, toks)[p], w["embed"].T, precision="highest")))
     rng = np.random.default_rng(5)
     sample = []
     for _ in range(4):  # greedy decode with the float32 pass: the exact program
@@ -42,7 +44,7 @@ def test_control_reads_wider_gaps_than_the_exact_program():
             served.append(nxt)
             toks[p + 1] = nxt
         sample.append((prompt, served))
-    got = reference.gaps(C, w, sample, L, control=True)
+    got = reference.gaps(dense, C, w, sample, L, control=True)
     assert got["tokens"] == 160
     assert got["max_logit_gap"] <= 1e-5
     assert got["control_max_logit_gap"] > 1e-3, json.dumps(got)

@@ -1,8 +1,9 @@
 """A small cell for the benchmark's CPU tests: qwen3-1.7b's program at small
 widths (wide and deep enough that attention over the cache, and not the tied
 input embedding, sets the next token), a small plan without rolled decode
-spans (as the chip cells derive), and copies of the benchmark's generators
-and metric readers in a temporary directory laid out like ``bench/``."""
+spans (as the chip cells derive), and copies of the benchmark's generators,
+metric readers and architecture modules in a temporary directory laid out
+like ``bench/``."""
 from __future__ import annotations
 
 import dataclasses
@@ -39,7 +40,7 @@ def make(tmp, limit: float = 0.05, checks: dict = None) -> "object":
     from bench import registry
 
     base = pathlib.Path(tmp) / "bench"
-    for d in ("generators", "metrics"):
+    for d in ("generators", "metrics", "archs"):
         shutil.copytree(ROOT / "bench" / d, base / d, dirs_exist_ok=True)
     for d in ("configs", "traffic", "checks"):
         (base / d).mkdir(parents=True, exist_ok=True)
