@@ -21,6 +21,8 @@ import pytest
 from bench import flops, model, reference, registry
 from bench import weights as W
 
+import specrules
+
 PINNED = json.loads((pathlib.Path(__file__).parent / "data" / "arch_dense_pinned.json").read_text())
 C = PINNED["config"]
 dense = registry.Registry().arch(C)
@@ -34,7 +36,8 @@ def _draw(arch, c, seed):
 def test_a_file_without_arch_module_is_served_as_dense():
     reg = registry.Registry()
     for c in reg.spec["configs"]:
-        assert "arch_module" not in reg.config(c["name"])
+        specrules.served_by_its_module(reg, c["name"])
+    assert "arch_module" not in reg.config("qwen3-1.7b")
     assert pathlib.Path(dense.__file__) == registry.BENCH / "archs" / "dense.py"
 
 

@@ -1,20 +1,23 @@
 """The control: the reference computed in int8 in place of the program (the
 precision below the configurations' stated bfloat16) reads a wider logit
 gap than the program, and fails the limit that the program meets.  On the
-CPU the program's matmuls are float32, so its gap is 0; the chip's readings
-and the limits set from them are in PERF.md."""
+CPU the program's matmuls are float32, so its gap is 0.  On the chip each
+cell of ``BENCHMARK.json`` brings the readings of its control runs as a
+file of its own, ``data/control/<cell>.json``; its committed limits are
+held against them here, and the limits and the readings they were set from
+are in PERF.md."""
 import benchpath  # noqa: F401  (first: puts bench/ on the path)
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, reference, registry
+from bench import reference, registry
 from bench import weights as W
 
+import specrules
 import tinybench
 
 dense = registry.Registry().arch({})
@@ -25,7 +28,7 @@ C = {"num_hidden_layers": 4, "hidden_size": 512, "num_attention_heads": 8,
      "num_key_value_heads": 4, "head_dim": 64, "intermediate_size": 1024,
      "vocab_size": 8192, "rope_theta": 10000.0, "rms_norm_eps": 1e-6, "qk_norm": False}
 L = 128
-DATA = pathlib.Path(__file__).parent / "data"
+CELLS = [w["name"] for w in registry.Registry().spec["workloads"]]
 
 
 def test_control_reads_wider_gaps_than_the_exact_program():
@@ -61,15 +64,10 @@ def test_control_run_is_judged_by_the_control(tmp_path):
     assert out["correct"] is all(v["value"] <= v["limit"] for v in out["check"].values())
 
 
-@pytest.mark.parametrize("cell", ["qwen3-1.7b.code", "qwen3-1.7b.batch"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_committed_limits_fail_the_chip_control_and_pass_the_program(cell):
     """The readings of this cell's control runs on the chip, at its own size
-    and load (``data/control_readings.json``, PERF.md): under the committed
-    limits each reads ``correct`` false, and the program's own gaps on the
-    same samples read true."""
-    readings = json.loads((DATA / "control_readings.json").read_text())[cell]
-    check = registry.load_json(registry.BENCH / "checks" / f"{cell}.json")
-    assert len(readings) >= 3
-    for r in readings:
-        assert harness.decide(r["control"], check, 0)[0] is False, r
-        assert harness.decide(r["program"], check, 0)[0] is True, r
+    and load (``data/control/<cell>.json``): under the committed limits each
+    reads ``correct`` false, and the program's own gaps on the same samples
+    read true.  A cell without the file fails here."""
+    specrules.limits_fail_the_control(registry.Registry(), cell)

@@ -1,9 +1,13 @@
-"""A small cell for the benchmark's CPU tests: qwen3-1.7b's program at small
-widths (wide and deep enough that attention over the cache, and not the tied
-input embedding, sets the next token), a small plan without rolled decode
-spans (as the chip cells derive), and copies of the benchmark's generators,
-metric readers and architecture modules in a temporary directory laid out
-like ``bench/``."""
+"""Small cells for the benchmark's CPU tests.  They stand for one
+configuration of a benchmark spec, named by the caller (qwen3-1.7b of the
+repo's ``BENCHMARK.json`` by default): ``tiny.<traffic>`` for each of its
+cells whose traffic has a small mix here, served as a small configuration
+file (``CONFIG``: qwen3-1.7b's program at small widths, wide and deep enough
+that attention over the cache, and not the tied input embedding, sets the
+next token) through the architecture module that configuration names, with
+a small plan without rolled decode spans (as the chip cells derive).  The
+spec's generators, metric readers and architecture modules are copied into
+a temporary directory laid out like ``bench/``."""
 from __future__ import annotations
 
 import dataclasses
@@ -31,49 +35,72 @@ SERVE = dict(decode_batch=4, mixed_slab_width=16, prefill_chunk=16, n_blocks=1 +
              rolled_steps=1)
 
 
-def make(tmp, limit: float = 0.05, checks: dict = None) -> "object":
-    """The tiny cells' registry.  Their limit: the small plan keeps bf16 pages,
-    so a served token flips at a near tie by a few thousandths of a logit on
-    the CPU, where a broken step reads gaps of whole logits.  ``checks``
-    ({tiny cell: benchmark cell}) takes a benchmark cell's own check file
-    instead."""
+def make(tmp, limit: float = 0.05, checks: dict = None, *, spec=None,
+         stands_for: str = "qwen3-1.7b", config: dict = CONFIG) -> "object":
+    """The tiny cells' registry, for configuration ``stands_for`` of the spec
+    at path ``spec`` (the repo's ``BENCHMARK.json`` by default; its parts are
+    read from the ``bench/`` beside it).  The tiny file ``config`` keeps that
+    configuration's ``arch`` and ``arch_module``.  Each metric's
+    ``workloads`` keeps the cells that have a tiny one, renamed; a list left
+    empty reports in no tiny cell.  Their limit: the small plan keeps bf16
+    pages, so a served token flips at a near tie by a few thousandths of a
+    logit on the CPU, where a broken step reads gaps of whole logits.
+    ``checks`` ({tiny cell: benchmark cell}) takes a benchmark cell's own
+    check file instead."""
     from bench import registry
+
+    spec_path = pathlib.Path(spec or ROOT / "BENCHMARK.json")
+    src = spec_path.parent / "bench"
+    spec = registry.load_json(spec_path)
+    real = registry.Registry(src, spec).config(stands_for)
+    for key in ("arch", "arch_module"):
+        if config.get(key) != real.get(key):
+            raise ValueError(f"the tiny file's {key} {config.get(key)!r} is not "
+                             f"{stands_for}'s {real.get(key)!r}")
+    cells = {w["name"]: w["traffic"] for w in spec["workloads"]
+             if w["config"] == stands_for and w["traffic"] in TRAFFIC}
 
     base = pathlib.Path(tmp) / "bench"
     for d in ("generators", "metrics", "archs"):
-        shutil.copytree(ROOT / "bench" / d, base / d, dirs_exist_ok=True)
+        shutil.copytree(src / d, base / d, dirs_exist_ok=True)
     for d in ("configs", "traffic", "checks"):
         (base / d).mkdir(parents=True, exist_ok=True)
-    (base / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
-    for name, t in TRAFFIC.items():
-        (base / "traffic" / f"{name}.json").write_text(json.dumps(t))
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (base / "configs" / "tiny.json").write_text(json.dumps(config))
     spec["configs"] = [{"name": "tiny", "file": "bench/configs/tiny.json"}]
     spec["workloads"] = [{"name": f"tiny.{t}", "config": "tiny", "traffic": t, "chips": 1}
-                         for t in TRAFFIC]
-    rename = {"qwen3-1.7b.batch": "tiny.batch", "qwen3-1.7b.code": "tiny.code"}
+                         for t in cells.values()]
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = sorted({rename[w] for w in m["workloads"]})
+            m["workloads"] = sorted(f"tiny.{cells[w]}" for w in m["workloads"] if w in cells)
     for w in spec["workloads"]:
+        t = w["traffic"]
+        (base / "traffic" / f"{t}.json").write_text(json.dumps(TRAFFIC[t]))
         check = {"limits": {"max_logit_gap": limit, "mean_logit_gap": limit / 10},
                  "tokens": 40, "max_requests": 8}
         if checks:
-            committed = json.loads((ROOT / "bench" / "checks" / f"{checks[w['name']]}.json").read_text())
-            check["limits"] = committed["limits"]
+            check["limits"] = registry.load_json(src / "checks" / f"{checks[w['name']]}.json")["limits"]
         (base / "checks" / f"{w['name']}.json").write_text(json.dumps(check))
     return registry.Registry(base, spec)
+
+
+def program_cfg(arch, c: dict):
+    """The program's ArchConfig for tiny file ``c``: the registry arch it
+    names, with every field that architecture module ``arch`` maps from the
+    file (``PROGRAM_KEYS``: file key -> ArchConfig field) set as the file
+    states."""
+    from repro.configs import get_config
+
+    keys = getattr(arch, "PROGRAM_KEYS", None)
+    if keys is None:
+        raise AttributeError(f"architecture module {arch.__name__} has no PROGRAM_KEYS")
+    return dataclasses.replace(get_config(c["arch"]), **{f: c[k] for k, f in keys.items()})
 
 
 def run(reg, workload: str, seconds: float = 2.0, seed: int = 2**31 + 12345, **kw) -> dict:
     import jax
     from bench import harness
-    from repro.configs import get_config
 
-    cfg = dataclasses.replace(
-        get_config("qwen3-1.7b"), d_model=CONFIG["hidden_size"], d_ff=CONFIG["intermediate_size"],
-        n_heads=CONFIG["num_attention_heads"], n_kv_heads=CONFIG["num_key_value_heads"],
-        d_head=CONFIG["head_dim"], vocab_size=CONFIG["vocab_size"])
+    c = reg.config(reg.cell(workload)["config"])
     return harness.run_cell(workload, seed, seconds, False, reg=reg, devices=jax.devices(),
-                            program_cfg=cfg,
+                            program_cfg=program_cfg(reg.arch(c), c),
                             serve_overrides=SERVE, **kw)
