@@ -262,12 +262,12 @@ def step_hlo(engine) -> str:
     """Optimized HLO of the engine's unified step, as compiled for its
     pools and parameters."""
     sp = engine.serve
-    B, W, MB = sp.decode_batch, sp.mixed_slab_width, sp.max_blocks_per_seq
+    B, T, MB = sp.decode_batch, sp.step_tokens, sp.max_blocks_per_seq
     spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     lowered = engine._step.lower(
         jax.tree.map(spec, engine.params), jax.tree.map(spec, engine.pools),
-        i32(B, W), i32(B, MB), i32(B), i32(B), jax.ShapeDtypeStruct((B,), jnp.float32),
+        i32(3, T), i32(B, MB), i32(B), i32(B), jax.ShapeDtypeStruct((B,), jnp.float32),
     )
     return lowered.compile().as_text()
 

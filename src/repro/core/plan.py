@@ -421,9 +421,10 @@ class ServePlan:
     prefill_chunk: int
     # Serving context bound: block tables cover exactly this many positions.
     max_seq_len: int
-    # Width of the unified mixed prefill/decode slab: every slot owns this
-    # many query rows per step (decode uses 1, a prefill chunk up to all of
-    # them).  Wider slabs prefill faster but pay dead rows while decoding.
+    # The most query rows one slot takes in a step (decode uses 1, a
+    # speculating slot gamma+1, a prefill chunk up to all of them).  The
+    # dense layers run the step's packed tokens (``step_tokens``), and the
+    # paged kernel gets them back as a (B, W) slab of this width.
     mixed_slab_width: int = 1
     # KV pages streamed into one VMEM tile per kernel grid step (the fused
     # paged-attention kernel's tile height), from the VMEM budget.
@@ -501,6 +502,24 @@ class ServePlan:
     hbm_kv_budget_bytes: int = 0
 
     @property
+    def step_tokens(self) -> int:
+        """T, the static length of the mixed step's packed token stream:
+        the smallest multiple of 128 that holds every running slot's rows
+        (``decode_batch * (1 + spec_len)``) and four prefill chunks of
+        ``mixed_slab_width``, and never more than the whole (B, W) slab,
+        which no step can outgrow.  Four chunks, not two: a step pays a
+        fixed cost (the weight stream, the pool's per-layer slices, the
+        paged kernel over every running slot) that dwarfs a row's share of
+        the dense layers, so a burst of prompts — a server filling its
+        slots, arrivals that bunch — clears in half the steps for a few
+        per cent more on every step.  A property of B, W and gamma, so a
+        plan rebuilt with ``dataclasses.replace`` keeps a budget that fits
+        it."""
+        B, W = self.decode_batch, self.mixed_slab_width
+        need = B * (1 + self.spec_len) + 4 * W
+        return min(-(-need // 128) * 128, B * W)
+
+    @property
     def max_concurrency(self) -> int:
         """Requests the block pool can hold at full context length."""
         return (self.n_blocks - 1) // self.max_blocks_per_seq
@@ -510,7 +529,8 @@ class ServePlan:
             f"serve plan for {self.arch}: decode_batch={self.decode_batch} "
             f"block_size={self.block_size} n_blocks={self.n_blocks} "
             f"kv_dtype={self.kv_dtype} prefill_chunk={self.prefill_chunk} "
-            f"slab={self.mixed_slab_width} pages/tile={self.pages_per_tile} "
+            f"slab={self.mixed_slab_width} step_tokens={self.step_tokens} "
+            f"pages/tile={self.pages_per_tile} "
             f"fused={self.fused_attention} rolled_steps={self.rolled_steps} "
             f"spec_len={self.spec_len} "
             f"draft={self.draft} prefix_sharing={self.prefix_sharing} "
@@ -528,6 +548,7 @@ class ServePlan:
             "kv_dtype": self.kv_dtype,
             "prefill_chunk": self.prefill_chunk,
             "mixed_slab_width": self.mixed_slab_width,
+            "step_tokens": self.step_tokens,
             "pages_per_tile": self.pages_per_tile,
             "fused_attention": self.fused_attention,
             "rolled_steps": self.rolled_steps,
@@ -646,10 +667,11 @@ def derive_serve_plan(
       the cache instead of the weights).
     * **block size** — one MXU sublane tile (``mxu_dim // 8``) so a page
       feeds the MM PU without re-tiling; never wider than the context.
-    * **mixed-slab width** — query rows per slot in the unified step;
-      defaults to ``prefill_chunk`` (prefill keeps its compute-bound chunk,
-      decode slots carry the dead rows — the explicit latency/throughput
-      trade, overridable).
+    * **mixed-slab width** — the most query rows one slot takes in the
+      unified step; defaults to ``prefill_chunk`` (prefill keeps its
+      compute-bound chunk).  The step's dense layers run a packed stream
+      of ``ServePlan.step_tokens`` rows, derived from the batch, this width
+      and gamma, so a decode slot costs one row, not W.
     * **pages per VMEM tile** — the largest block-table divisor at which
       the fused kernel's whole footprint for one grid step (q and output
       blocks, the softmax carry, the score arrays and the page tile with

@@ -80,26 +80,30 @@ def attention_stage(
 
     new_cache = None
     if cache is not None and "paged" in cache:
-        # Continuous-batching serve path: write this step's KV into the
-        # block pool at each slot's own positions, then attend straight off
-        # the block table (prefill-chunk rows and decode rows are the same
-        # code — ``q_lens`` says how many slab rows are live per slot).
+        # Continuous-batching serve path: ``h`` is a packed stream of T
+        # tokens, one a row (S == 1), and ``page_state`` says where each
+        # sits in the (B, W) slab the paged kernel takes (``slab_index``).
+        # Each token writes its KV at its own (slot, position); a padding
+        # row writes to the trash block.  q goes out to the slab, and the
+        # kernel's output comes back to the T rows before ``wo``.
         bs = page_state["block_size"]
-        table = page_state["table"]
-        q_lens = page_state.get("q_lens")
-        pos2d = jnp.broadcast_to(positions, (B, S)).astype(jnp.int32)
-        valid = None
-        if q_lens is not None:  # dead slab rows write to the trash block
-            valid = jnp.arange(S)[None, :] < q_lens[:, None]
-        entry = C.paged_update(cache["paged"], k, v, pos2d, table, bs, valid)
+        table, lens, q_lens = page_state["table"], page_state["lens"], page_state["q_lens"]
+        flat, inv = page_state["flat"], page_state["inv"]
+        nb = table.shape[0]
+        W = inv.shape[0] // nb
+        live = (flat < nb * W)[:, None]
+        entry = C.paged_update(
+            cache["paged"], k, v, positions, table[page_state["slot"]], bs, live
+        )
+        fill = dict(axis=0, mode="fill", fill_value=0)
+        qs = jnp.take(q.reshape(B, H, Dh), inv, **fill).reshape(nb, W, H, Dh)
         if page_state.get("fused"):
             # Fused Pallas kernel: walks the table, streams pages into VMEM
             # tiles, dequantizes int8 in-kernel — no dense gather in HBM.
             from repro.kernels.paged_attention.ops import paged_attention
 
-            ql = q_lens if q_lens is not None else jnp.full((B,), S, jnp.int32)
             o = paged_attention(
-                q, entry, table, pos2d[:, 0], ql,
+                qs, entry, table, lens, q_lens,
                 block_size=bs, window=window,
                 pages_per_tile=page_state.get("pages_per_tile", 0),
             )
@@ -108,7 +112,9 @@ def attention_stage(
             # pages — clamped to the live high-water mark when concrete —
             # then attend with per-slot masks.
             kf, vf = C.paged_gather(entry, table, bs)
-            o = L.paged_attention(q, kf, vf, pos2d, window=window)
+            pos2d = lens[:, None] + jnp.arange(W)[None, :]
+            o = L.paged_attention(qs, kf, vf, pos2d, window=window)
+        o = jnp.take(o.reshape(nb * W, H, Dh), flat, **fill)
         out = shard(o.reshape(B, S, H * Dh), "act_heads_flat") @ ap["wo"]
         return out, {"paged": entry}, None
     if cache is None:
@@ -133,6 +139,26 @@ def attention_stage(
         kv_out = None
     out = shard(o.reshape(B, S, H * Dh), "act_heads_flat") @ ap["wo"]
     return out, new_cache, kv_out
+
+
+def slab_index(slot, pos, lens, q_lens, width: int):
+    """Where the tokens of a packed serve stream sit in the (B, W) slab.
+
+    Token t belongs to slot ``slot[t]`` at absolute position ``pos[t]``; it
+    is live iff ``lens[b] <= pos[t] < lens[b] + q_lens[b]`` for its slot b
+    (the scheduler pads the stream with ``pos = -1``).  Returns ``(flat,
+    inv)``: ``flat`` (T,) is each token's slab row ``b * W + pos - lens[b]``
+    (B·W on a padding row), ``inv`` (B·W,) the token at each slab row (T on
+    a dead row) — so ``take(..., mode="fill")`` moves rows either way and
+    fills the dead ones with zeros."""
+    B, T = lens.shape[0], slot.shape[0]
+    row = pos - lens[slot]
+    live = (row >= 0) & (row < q_lens[slot])
+    flat = jnp.where(live, slot * width + row, B * width).astype(jnp.int32)
+    inv = jnp.full((B * width,), T, jnp.int32).at[flat].set(
+        jnp.arange(T, dtype=jnp.int32), mode="drop"
+    )
+    return flat, inv
 
 
 def cross_attention_stage(cp: dict, h: jax.Array, memory_kv, cfg: ArchConfig):
@@ -509,10 +535,12 @@ def forward(
     layer-groups run through the Megatron-SP manual-collective path
     (:func:`sp_stack_forward`); everything else stays on the GSPMD path.
 
-    With a *paged* ``cache`` (``models/cache.init_paged_cache``) and
-    ``page_state={"table": (B, MB) int32, "block_size": int}``, the pass is a
-    continuous-batching serve step: ``cache["t"]`` is a per-slot (B,) length
-    vector and S may be a prefill chunk width (>= 1).
+    With a *paged* ``cache`` (``models/cache.init_paged_cache``) the pass is
+    a continuous-batching serve step over a packed stream: "tokens" is
+    (T, 1), ``cache["t"]`` the (T,) absolute position of each token, and
+    ``page_state`` holds the block table (B, MB), the per-slot ``lens`` and
+    ``q_lens`` (B,), each token's ``slot`` (T,) and the slab map
+    :func:`slab_index` derives from them (``serve/engine.make_mixed_step``).
     """
     dtype = _weight_dtype(params)
     x, positions, prefix_len = _embed_inputs(params, batch, cfg, cache, dtype)

@@ -10,18 +10,20 @@ Two layers:
   (``models/cache.init_paged_cache``) with at most TWO static-shape jitted
   device programs: the unified mixed prefill/decode step, plus (when the
   plan's ``rolled_steps`` > 1) the rolled decode loop that runs K decode
-  iterations per dispatch.  Every slot owns
-  ``mixed_slab_width`` query rows of a shared (B, W) token slab — a decode
-  slot uses 1, a prefill slot up to W (its next prompt chunk), idle rows
-  are dead and write to the trash block — so prefilling new requests rides
-  in whatever rows the decode batch isn't using instead of stalling it for
-  an iteration.  Attention runs through the fused Pallas paged-attention
-  kernel (``kernels/paged_attention``), which consumes the block table
-  directly; the dense gather fallback only remains for model-sharded
-  meshes (GSPMD cannot partition the kernel yet) and as the oracle.
-  Scheduling policy lives host-side in ``serve/scheduler.py``; the knobs
-  (decode batch, block size, KV dtype, slab width, pages per VMEM tile)
-  come from ``core/plan.derive_serve_plan``.
+  iterations per dispatch.  The mixed step runs a packed stream of
+  ``ServePlan.step_tokens`` tokens, each tagged with its slot and position
+  — a decode slot contributes 1, a prefill slot up to
+  ``mixed_slab_width`` (its next prompt chunk), and the rows past the live
+  tokens are padding that writes to the trash block — so prefilling new
+  requests rides in the same step as the decode batch, and the dense
+  layers run on the live tokens, not on a (B, W) slab.  Attention gathers
+  q into the (B, W) slab of the fused Pallas paged-attention kernel
+  (``kernels/paged_attention``), which consumes the block table directly,
+  and gathers its output back; the dense gather fallback only remains for
+  model-sharded meshes (GSPMD cannot partition the kernel yet) and as the
+  oracle.  Scheduling policy lives host-side in ``serve/scheduler.py``; the
+  knobs (decode batch, block size, KV dtype, slab width, token budget,
+  pages per VMEM tile) come from ``core/plan.derive_serve_plan``.
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ from repro.models.cache import (
     init_paged_cache,
     paged_copy_block,
 )
-from repro.models.transformer import forward, logits_fn
+from repro.models.transformer import forward, logits_fn, slab_index
 from repro.obs import Observability, Phases
 from repro.obs.calibrate import step_time_model
 from repro.serve.faults import (
@@ -123,14 +125,21 @@ def make_mixed_step(
 ):
     """Build the ONE jitted unified mixed prefill/decode step.
 
-    ``step(params, pools, tokens (B, W), tables (B, MB), lens (B,),
-    kinds (B,), poison (B,))`` returns ``(tok, vtok, finite, pools)``:
-    ``tok[b]`` is the greedy token at slot b's last live row; ``vtok``
-    (B, spec_width) is the greedy argmax at each of the slot's leading
-    rows — the verification targets of speculative decoding (row i scores
-    the token that should follow the slot's i-th slab token).  With
-    ``spec_width == 1`` no extra logits are computed and ``vtok`` is just
-    ``tok[:, None]``.
+    ``step(params, pools, stream (3, T), tables (B, MB), lens (B,), q_lens
+    (B,), poison (B,))`` returns ``(tok, vtok, finite, pools)``.  The step
+    runs a packed stream of ``T = serve.step_tokens`` tokens, whose rows are
+    ``tokens``, ``slot`` and ``pos`` (``serve/scheduler.Slab``): token t is
+    slot ``slot[t]``'s token at absolute position ``pos[t]``, slot b's
+    ``q_lens[b]`` tokens sit at positions ``lens[b]`` on, and rows past the
+    live tokens are padding (``pos = -1``; their KV goes to the trash
+    block).  The dense layers run on the T rows only; attention takes them
+    through the paged kernel's (B, W) slab
+    (``models/transformer.slab_index``).  ``tok[b]`` is the greedy token
+    at slot b's last live row; ``vtok`` (B, spec_width) is the greedy
+    argmax at each of the slot's leading rows — the verification targets
+    of speculative decoding (row i scores the token that should follow the
+    slot's i-th token).  With ``spec_width == 1`` no extra logits are
+    computed and ``vtok`` is just ``tok[:, None]``.
 
     ``finite[b]`` is the on-device health scalar — one bool per slot,
     false when any logit the slot sampled from is non-finite — and the
@@ -141,27 +150,37 @@ def make_mixed_step(
 
     Shared by :class:`ServingEngine` and the model drafter
     (``serve/speculative.ModelDraft``) — the drafter is mechanically a
-    second, smaller serving engine riding the same slab contract."""
+    second, smaller serving engine riding the same step contract."""
     page_state = {
         "block_size": serve.block_size,
         "fused": bool(fused),
         "pages_per_tile": serve.pages_per_tile,
     }
+    W = serve.mixed_slab_width
 
-    def step_fn(params, pools, tokens, tables, lens, kinds, poison):
+    def step_fn(params, pools, stream, tables, lens, q_lens, poison):
         if trace is not None:
             trace[trace_key] += 1
-        cache = {"layers": pools["layers"], "t": lens}
+        B = lens.shape[0]
+        tokens, slot, pos = stream
+        flat, inv = slab_index(slot, pos, lens, q_lens, W)
         x, nc, _ = forward(
-            params, {"tokens": tokens}, cfg=cfg, plan=plan, cache=cache,
-            shard=shard,
-            page_state={**page_state, "table": tables, "q_lens": kinds},
+            params, {"tokens": tokens[:, None]}, cfg=cfg, plan=plan,
+            cache={"layers": pools["layers"], "t": pos}, shard=shard,
+            page_state={
+                **page_state, "table": tables, "lens": lens, "q_lens": q_lens,
+                "slot": slot, "flat": flat, "inv": inv,
+            },
         )
-        # per-slot greedy token at the last live row (kinds-1; row 0 for
+        # the token at each slab row (T, so a zero row, where it is dead)
+        rows = inv.reshape(B, W)
+        fill = dict(axis=0, mode="fill", fill_value=0)
+        # per-slot greedy token at the last live row (q_lens-1; row 0 for
         # decode slots, the final prompt token on a last prefill chunk)
-        idx = jnp.maximum(kinds - 1, 0)
-        xl = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-        logits = logits_fn(params, xl, cfg)[:, -1] + poison[:, None]
+        idx = jnp.maximum(q_lens - 1, 0)
+        last = jnp.take_along_axis(rows, idx[:, None], axis=1)
+        xl = jnp.take(x[:, 0], last[:, 0], **fill)
+        logits = logits_fn(params, xl, cfg) + poison[:, None]
         tok = jnp.argmax(logits, axis=-1)
         # one extra scalar per slot: a NaN/Inf anywhere in the sampled
         # logits poisons the sum, so isfinite(sum) is the whole check
@@ -169,7 +188,8 @@ def make_mixed_step(
         if spec_width > 1:
             # verification targets: the target model's own greedy choice
             # after every leading row (drafted rows ride rows 1..gamma)
-            vlog = logits_fn(params, x[:, :spec_width], cfg)
+            xv = jnp.take(x[:, 0], rows[:, :spec_width], **fill)
+            vlog = logits_fn(params, xv, cfg)
             vlog = vlog + poison[:, None, None]
             vtok = jnp.argmax(vlog, axis=-1)
             finite = finite & jnp.isfinite(jnp.sum(vlog, axis=(-2, -1)))
@@ -233,6 +253,7 @@ def make_rolled_step(
         if trace is not None:
             trace[trace_key] += 1
         B = tok.shape[0]
+        slots = jnp.arange(B, dtype=jnp.int32)
 
         def cond(state):
             i, dead = state[0], state[5]
@@ -242,10 +263,15 @@ def make_rolled_step(
             i, tok, lens, layers, out, dead = state
             live = (steps_left > i) & ~dead
             kinds = live.astype(jnp.int32)
+            # a packed stream of B tokens, one a slot, in a width-1 slab
+            flat, inv = slab_index(slots, lens, lens, kinds, 1)
             x, nc, _ = forward(
                 params, {"tokens": tok[:, None]}, cfg=cfg, plan=plan,
                 cache={"layers": layers, "t": lens}, shard=shard,
-                page_state={**page_state, "table": tables, "q_lens": kinds},
+                page_state={
+                    **page_state, "table": tables, "lens": lens,
+                    "q_lens": kinds, "slot": slots, "flat": flat, "inv": inv,
+                },
             )
             logits = logits_fn(params, x, cfg)[:, -1]
             logits = logits + jnp.where(poison == i, jnp.float32(jnp.nan), 0.0)[
@@ -332,15 +358,17 @@ class ServingEngine:
 
     Exactly ONE jitted device program with static shapes:
 
-    * ``step(params, pools, token_slab (B, W), tables (B, MB), lens (B,),
-      kinds (B,))`` — ``kinds[b]`` is slot b's live query-row count (0 idle,
-      1 decode, n <= W prefill chunk); ``lens[b]`` the absolute position of
-      its first row.  Each slot's KV rides its own block-table row, the
+    * ``step(params, pools, stream (3, T), tables (B, MB), lens (B,),
+      kinds (B,), poison (B,))`` — a packed stream of ``T =
+      serve.step_tokens`` tokens, each with its slot and position;
+      ``kinds[b]`` is slot b's live token count (0 idle or deferred, 1
+      decode, n <= W prefill chunk), ``lens[b]`` the absolute position of
+      its first token.  Each slot's KV rides its own block-table row, the
       fused paged-attention kernel masks per slot, and the returned greedy
       token is taken at the slot's last live row — a runner's next token,
       or the first output of a request whose final prompt chunk this was.
 
-    The scheduler packs the slab per iteration: admit, grow, one mixed
+    The scheduler packs the stream per iteration: admit, grow, one mixed
     step.  ``trace_counts`` proves there is no per-request retracing — it
     stays bounded by {"step": 1, "rolled_step": 1} however the stream
     churns (the second program is the rolled decode loop, compiled at most
@@ -451,6 +479,11 @@ class ServingEngine:
             # query rows per KV head and layer the paged kernel computes on
             # a live tile, and those carrying a token (``_count_attn_rows``)
             "attn_rows": 0, "attn_live_rows": 0,
+            # rows the dense layers run (``step_tokens`` a mixed step, B a
+            # rolled iteration), and how often the step's token budget left
+            # a prefilling slot short, by how many rows
+            "rows_computed": 0, "budget_bound_steps": 0,
+            "prefill_rows_deferred": 0,
         }
         self._phases = Phases(self.obs.tracer, self.stats)
         # degradation ladder: 0 = rolled-K spans, 1 = K=1 mixed step,
@@ -715,22 +748,21 @@ class ServingEngine:
         drafts = self._propose_drafts()
         s._grow_for_decode({rid: len(d) for rid, d in drafts.items()})
         if s.busy():
-            tokens, tables, lens, kinds = s._slab_view(
-                self.serve.mixed_slab_width, drafts
-            )
+            slab = s._slab_view(self.serve.mixed_slab_width, drafts)
+            kinds = slab.kinds
             # slab composition + roofline price, snapshotted pre-dispatch
-            # (``_slab_done`` mutates slot states)
-            ka = np.asarray(kinds)
+            # (``_slab_done`` mutates slot states); a prefilling slot the
+            # budget left out counts as idle this step
             composition = {
-                "idle": int((ka == 0).sum()),
-                "decode": int((ka == 1).sum()),
-                "prefill": len(s.prefilling()),
+                "idle": int((kinds == 0).sum()),
+                "decode": int((kinds == 1).sum()),
+                "prefill": sum(1 for r in s.prefilling() if kinds[r.slot]),
                 "spec": sum(1 for r in s.running() if r.rid in drafts),
             }
-            rows = int(ka.sum())
+            rows = int(kinds.sum())
             phase = "prefill" if composition["prefill"] else "decode"
             predicted_s = self._cost.predict_s(
-                rows, float(np.asarray(lens).sum()) + rows
+                rows, float(slab.lens.sum()) + rows
             )
             while not self._retry_transients():
                 if not self._escalate():
@@ -748,8 +780,8 @@ class ServingEngine:
                 if sp:
                     time.sleep(sp)
             sampled, vtok, finite, self.pools = step_fn(
-                self.params, self.pools, tokens, tables, lens, kinds,
-                jnp.asarray(poison),
+                self.params, self.pools, slab.stream, slab.tables, slab.lens,
+                kinds, jnp.asarray(poison),
             )
             ph.to("engine.sync")
             sampled = np.asarray(sampled)  # block for an honest step time
@@ -789,8 +821,11 @@ class ServingEngine:
             self.stats["occupancy_sum"] += (
                 int((kinds > 0).sum()) / self.serve.decode_batch
             )
+            self.stats["rows_computed"] += len(slab.tokens)
+            self.stats["budget_bound_steps"] += int(slab.deferred > 0)
+            self.stats["prefill_rows_deferred"] += slab.deferred
             if self.fused and trace_key == "step":
-                self._count_attn_rows(ka, self.serve.mixed_slab_width)
+                self._count_attn_rows(kinds, self.serve.mixed_slab_width)
         self.iteration += 1
 
     def _rolled_dispatch(self, k: int, steps: np.ndarray) -> bool:
@@ -875,6 +910,7 @@ class ServingEngine:
         # same unit as the K=1 path: live-slot fraction summed per device
         # iteration (slot b is live for its first steps[b] iterations)
         self.stats["occupancy_sum"] += float(steps.sum()) / self.serve.decode_batch
+        self.stats["rows_computed"] += self.serve.decode_batch * adv
         if self.fused:
             self._count_attn_rows(np.asarray(steps) > 0, 1, reps=steps)
         self.iteration += adv

@@ -20,15 +20,18 @@ Policy (Orca-style iteration-level scheduling, token-level batching):
   refcount (no pages, no prefill), a mid-block divergence forks the block
   (copy-on-write: the engine copies the pages before its next step — see
   ``pending_copies``), and only the divergent tail is prefilled.
-* **slab packing** — every slot contributes rows to one (B, W) token slab
-  per iteration: a mid-prefill slot fills its row with its next prompt
-  chunk, a running slot carries its last sampled token in row 0, and idle
-  rows are dead (``kinds`` = live rows per slot; dead rows write to the
-  trash block).  Chunk sizing is SLO-aware: a prefill with a TTFT target
-  always takes the full width, and when one of them is at risk (measured
-  step time says the target needs more than half the slab's rate) the
-  SLO-less prefills throttle to one block per step so every step stays
-  short.
+* **packing** — every iteration's live tokens go into one packed stream
+  of ``ServePlan.step_tokens`` rows (:class:`Slab`): a running slot's last
+  sampled token (plus its drafts), then prompt chunks, each token tagged
+  with its slot and position; the rows past them are padding that writes
+  to the trash block (``kinds`` = live rows per slot).  Running slots
+  always get their rows; prefilling slots share the rest, oldest admitted
+  first, and one the budget leaves out waits for the next step with its
+  state intact.  Chunk sizing is SLO-aware: a prefill with a TTFT target
+  always asks for the full width, and when one of them is at risk
+  (measured step time says the target needs more than half the slab's
+  rate) the SLO-less prefills throttle to one block per step so every
+  step stays short.
 * **growth/eviction** — decode slots grow their block list lazily, one
   block at a time; when the pool is exhausted a requester may only evict
   runners strictly weaker than itself (lower priority, then younger), so
@@ -45,7 +48,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -86,6 +89,65 @@ def random_stream(
             )
         )
     return reqs
+
+
+class Slab(NamedTuple):
+    """One step's packed token stream (``serve/engine.make_mixed_step``).
+
+    ``stream`` (3, T) holds the rows ``tokens``, ``slot`` and ``pos``: token
+    t is slot ``slot[t]``'s token at absolute position ``pos[t]``.  A slot's
+    tokens are contiguous and in position order; rows past the live ones
+    are padding (token 0, slot 0, ``pos`` -1).  ``tables`` (B, MB), ``lens``
+    (B,) — the position of the slot's first token — and ``kinds`` (B,) —
+    its live tokens, 0 for a slot with none this step (its table row is
+    all-trash) — stay per slot.  ``deferred`` counts the prefill rows the
+    budget held back."""
+
+    stream: np.ndarray
+    tables: np.ndarray
+    lens: np.ndarray
+    kinds: np.ndarray
+    deferred: int = 0
+
+    @property
+    def tokens(self) -> np.ndarray:
+        return self.stream[0]
+
+    @property
+    def slot(self) -> np.ndarray:
+        return self.stream[1]
+
+    @property
+    def pos(self) -> np.ndarray:
+        return self.stream[2]
+
+
+def pack(rows: dict, table: np.ndarray, n_tokens: int, deferred: int = 0) -> Slab:
+    """Pack ``rows`` ({slot: (first position, [tokens])}) into a
+    :class:`Slab` of ``n_tokens`` rows, slots in ascending order, with
+    ``table`` (B, MB) the block tables to hand the step."""
+    B = table.shape[0]
+    stream = np.zeros((3, n_tokens), np.int32)
+    stream[2] = -1
+    tables = np.zeros_like(table)
+    lens = np.zeros((B,), np.int32)
+    kinds = np.zeros((B,), np.int32)
+    t = 0
+    for b in sorted(rows):
+        p, toks = rows[b]
+        n = len(toks)
+        if n == 0:
+            continue
+        if t + n > n_tokens:
+            raise ValueError(f"{t + n} rows do not fit a step of {n_tokens}")
+        stream[0, t : t + n] = toks
+        stream[1, t : t + n] = b
+        stream[2, t : t + n] = np.arange(p, p + n)
+        tables[b] = table[b]
+        lens[b] = p
+        kinds[b] = n
+        t += n
+    return Slab(stream, tables, lens, kinds, deferred)
 
 
 class BlockAllocator:
@@ -427,46 +489,38 @@ class Scheduler:
             return min(rem, width, self.serve.block_size)
         return min(rem, width)
 
-    def _slab_view(self, width: int, drafts: Optional[dict] = None):
-        """[internal] Pack one engine iteration's (B, W) token slab.
+    def _slab_view(self, width: int, drafts: Optional[dict] = None) -> Slab:
+        """[internal] Pack one engine iteration's token stream (:class:`Slab`)
+        under the plan's budget of ``step_tokens`` rows.
 
-        Returns (tokens, tables, lens, kinds) as numpy arrays:
-        ``kinds[b]`` is the number of live query rows of slot b — 0 for an
-        idle slot (whole row dead, table zeroed to the trash block), 1 for
-        a decode slot (its last sampled token), up to W for a prefill slot
-        (its next prompt chunk).  ``lens[b]`` is the absolute position of
-        the row's first token.
-
+        A running slot takes 1 row (its last sampled token) and a prefill
+        slot up to ``width`` (its next prompt chunk, ``_chunk_for``).
         ``drafts`` ({rid: [draft tokens]}, speculative decoding) turns a
         running slot's row into a gamma+1-token verification chunk: its
         last sampled token followed by the drafted continuation.  Keyed by
         rid, not slot, so drafts for a request evicted (or recycled) between
         proposal and packing are dropped on the floor instead of riding an
-        unrelated slot."""
-        B = self.serve.decode_batch
-        tokens = np.zeros((B, width), np.int32)
-        tables = np.zeros_like(self.table)
-        lens = np.zeros((B,), np.int32)
-        kinds = np.zeros((B,), np.int32)
+        unrelated slot.
+
+        The budget: every running slot gets its rows (``step_tokens`` holds
+        them all, drafts included); the prefilling slots share what is
+        left, oldest admitted first.  A prefilling slot the budget leaves
+        short takes the rows there are (``kinds[b]`` 0 when none) and keeps
+        its state; ``Slab.deferred`` counts the rows held back."""
+        rows: dict = {}
+        for req in self.running():
+            d = list((drafts or {}).get(req.rid, ()))[: width - 1]
+            rows[req.slot] = (int(self.lens[req.slot]), [req.out[-1]] + d)
+        budget = self.serve.step_tokens - sum(len(t) for _, t in rows.values())
         pressure = self._slo_pressure()
-        for b, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tables[b] = self.table[b]
-            if req.state == RUNNING:
-                row = [req.out[-1]]
-                if drafts:
-                    row += list(drafts.get(req.rid, ()))[: width - 1]
-                tokens[b, : len(row)] = row
-                lens[b] = self.lens[b]
-                kinds[b] = len(row)
-            elif req.state == PREFILL:
-                n = self._chunk_for(req, width, pressure)
-                chunk = req.prefill_target[req.pos : req.pos + n]
-                tokens[b, : len(chunk)] = chunk
-                lens[b] = req.pos
-                kinds[b] = len(chunk)
-        return tokens, tables, lens, kinds
+        deferred = 0
+        for req in sorted(self.prefilling(), key=lambda r: (r.t_admit, r.slot)):
+            n = self._chunk_for(req, width, pressure)
+            got = min(n, budget)
+            budget -= got
+            deferred += n - got
+            rows[req.slot] = (req.pos, req.prefill_target[req.pos : req.pos + got])
+        return pack(rows, self.table, self.serve.step_tokens, deferred)
 
     def _slab_done(
         self,

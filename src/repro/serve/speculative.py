@@ -4,7 +4,7 @@ CAT's serving roofline proves decode is bandwidth-bound — one weight stream
 per step with the MXU mostly idle.  Speculative decoding converts that idle
 compute into tokens: a drafter proposes gamma cheap continuation tokens per
 running slot, the engine packs them (plus the slot's real token) into the
-existing unified (B, W) slab as a gamma+1-row verification chunk, and the
+step's packed token stream as a gamma+1-row verification chunk, and the
 ONE jitted step scores every row at once.  The host keeps the longest draft
 prefix matching the target's own greedy argmax, so the emitted tokens are
 *exactly* what plain decode would have produced — any draft source only
@@ -20,7 +20,7 @@ Two draft sources:
   the CPU-interpret CI matrix; shines on repetitive continuations.
 * :class:`ModelDraft` — a small model (any ``configs/`` entry, e.g.
   smollm-135m drafting for qwen3-1.7b) with its *own* paged KV cache and
-  its own single jitted mixed step (the same slab contract as the target
+  its own single jitted mixed step (the same step contract as the target
   engine, one trace total).  Slot state is keyed by request id and
   self-heals: each proposal round diffs the target's actual sequence
   against what the drafter has cached and rolls its length vector back to
@@ -50,7 +50,7 @@ from repro.core.plan import (
 )
 from repro.models.cache import init_paged_cache
 from repro.serve.engine import make_mixed_step
-from repro.serve.scheduler import BlockAllocator
+from repro.serve.scheduler import BlockAllocator, pack
 
 Ask = tuple  # (rid, full token sequence so far, max drafts wanted)
 
@@ -112,15 +112,16 @@ class NGramDraft:
 
 class ModelDraft:
     """Model drafting: a small config runs greedy continuation on its own
-    paged cache through one jitted mixed step (the target engine's slab
+    paged cache through one jitted mixed step (the target engine's step
     contract in miniature).
 
     Proposal rounds are fully batched: every asking slot contributes rows
-    to one (B, Wd) draft slab per call — catch-up chunks (tokens the target
-    emitted that the drafter has not cached yet) and autoregressive draft
-    rows ride the same step, so a round costs
-    ``ceil(max_catchup / Wd) + gamma - 1`` device calls regardless of how
-    many slots speculate.
+    to one packed draft stream per call (the drafter plan's own
+    ``step_tokens``) — catch-up chunks (tokens the target emitted that the
+    drafter has not cached yet, up to Wd a slot) and autoregressive draft
+    rows ride the same step, so a round costs about
+    ``ceil(max_catchup / Wd) + gamma - 1`` device calls, more where the
+    catch-up outgrows the budget.
     """
 
     def __init__(
@@ -205,8 +206,7 @@ class ModelDraft:
             return {}
         n_dispatches = 0
         active = {rid for rid, _, _ in asks}
-        W = self.serve.mixed_slab_width
-        B = self.serve.decode_batch
+        W, T = self.serve.mixed_slab_width, self.serve.step_tokens
         state = {}  # slot -> [pending rows to feed, drafts, want]
         for rid, seq, n in asks:
             b = self._slot_for(rid, active)
@@ -223,27 +223,25 @@ class ModelDraft:
             state[b] = [list(seq[p:]), [], int(n)]
         while True:
             feeding = {}  # slot -> rows packed this call
-            tokens = np.zeros((B, W), np.int32)
-            tables = np.zeros_like(self.table)
-            lens = np.zeros((B,), np.int32)
-            kinds = np.zeros((B,), np.int32)
+            budget = T  # a slot the budget leaves out is fed next call
             for b, (pending, drafts, want) in state.items():
-                if len(drafts) >= want:
+                if len(drafts) >= want or budget == 0:
                     continue
-                rows = pending[:W] if pending else [drafts[-1]]
+                rows = pending[: min(W, budget)] if pending else [drafts[-1]]
                 if not self._ensure_blocks(b, len(self.toks[b]) + len(rows)):
                     state[b][2] = len(drafts)  # pool dry: freeze this slot
                     continue
                 feeding[b] = rows
-                tokens[b, : len(rows)] = rows
-                tables[b] = self.table[b]
-                lens[b] = len(self.toks[b])
-                kinds[b] = len(rows)
+                budget -= len(rows)
             if not feeding:
                 break
+            slab = pack(
+                {b: (len(self.toks[b]), rows) for b, rows in feeding.items()},
+                self.table, T,
+            )
             tok, _, _, self.pools = self._step(
-                self.params, self.pools, tokens, tables, lens, kinds,
-                self._no_poison,
+                self.params, self.pools, slab.stream, slab.tables, slab.lens,
+                slab.kinds, self._no_poison,
             )
             n_dispatches += 1
             tok = np.asarray(tok)

@@ -41,7 +41,7 @@ def _drive(s, serve, token=7):
     s.admit(10**9)
     s.drain_copies()
     s._grow_for_decode()
-    _, _, _, kinds = s._slab_view(serve.mixed_slab_width)
+    kinds = s._slab_view(serve.mixed_slab_width).kinds
     s._slab_done(np.full((serve.decode_batch,), token, np.int64), kinds)
 
 
@@ -119,11 +119,11 @@ def test_slo_chunk_sizing_throttles_sloless_prefills():
     assert not s._slo_pressure()  # no measured step time yet -> no pressure
     s.step_ms = 50.0  # measured steps are slow; 1ms TTFT is at risk
     assert s._slo_pressure()
-    _, _, _, kinds = s._slab_view(serve.mixed_slab_width)
+    kinds = s._slab_view(serve.mixed_slab_width).kinds
     assert kinds[urgent.slot] == 8  # full width
     assert kinds[bulk.slot] == 4  # throttled to one block
     s.step_ms = None
-    _, _, _, kinds = s._slab_view(serve.mixed_slab_width)
+    kinds = s._slab_view(serve.mixed_slab_width).kinds
     assert kinds[bulk.slot] == 8  # no pressure signal -> full width again
 
 

@@ -188,7 +188,7 @@ def _drive_slab(s, serve, token=7):
     """One host-side engine iteration against a fake device step."""
     s.admit(10**9)
     s.grow_for_decode()
-    tokens, tables, lens, kinds = s.slab_view(serve.mixed_slab_width)
+    kinds = s.slab_view(serve.mixed_slab_width).kinds
     s.slab_done(np.full((serve.decode_batch,), token, np.int64), kinds)
     return kinds
 
@@ -233,14 +233,14 @@ def test_grow_preempts_mid_prefill_holder_instead_of_crashing():
     s.submit(r0)
     s.submit(r1)
     s.admit(0)  # r0 admitted alone: 2 blocks
-    tokens, tables, lens, kinds = s.slab_view(4)
+    kinds = s.slab_view(4).kinds
     s.slab_done(np.full((2,), 3, np.int64), kinds)  # r0 RUNNING
     s.admit(1)  # r1 takes 4 blocks, 1 free; stays mid-prefill (8 > slab 4)
-    tokens, tables, lens, kinds = s.slab_view(4)
+    s.slab_view(4)
     assert r1.state == "prefill" and r1.blocks
     for _ in range(4):  # r0 decodes until the pool runs dry
         s.grow_for_decode()
-        _, _, _, kinds = s.slab_view(4)
+        kinds = s.slab_view(4).kinds
         s.slab_done(np.full((2,), 7, np.int64), kinds)
     assert s.n_evictions == 1
     assert r1.state == "waiting" and not r1.blocks
@@ -248,9 +248,12 @@ def test_grow_preempts_mid_prefill_holder_instead_of_crashing():
 
 
 def test_slab_view_masks_idle_and_mid_prefill_rows():
-    """Slab packing invariants: an idle slot's row is dead (kinds 0, table
-    all-trash); a mid-prefill slot carries its own chunk at its own offset
-    and its dead rows resolve to the trash block, never its pages."""
+    """Packing invariants: an idle slot has no rows (kinds 0, table
+    all-trash); a mid-prefill slot carries its own chunk at its own
+    positions; the padding rows past the live tokens resolve to the trash
+    block, never a slot's pages."""
+    from repro.models.transformer import slab_index
+
     cfg = get_config("smollm-135m").reduced()
     serve = _serve(
         cfg, decode_batch=3, block_size=4, prefill_chunk=4, max_seq_len=32
@@ -261,27 +264,40 @@ def test_slab_view_masks_idle_and_mid_prefill_rows():
     s.submit(r0)
     s.submit(r1)
     s.admit(0)
-    _, _, _, kinds = s.slab_view(4)
+    kinds = s.slab_view(4).kinds
     s.slab_done(np.full((3,), 3, np.int64), kinds)  # r0 RUNNING, r1 pos=4
     assert r0.state == "running" and r1.state == "prefill" and r1.pos == 4
-    tokens, tables, lens, kinds = s.slab_view(4)
-    assert kinds[r0.slot] == 1 and tokens[r0.slot, 0] == 3
-    assert kinds[r1.slot] == 4 and lens[r1.slot] == 4
-    assert tokens[r1.slot].tolist() == [9, 10, 11, 12]
+    s.grow_for_decode()  # r0's next position opens its second block
+    slab = s.slab_view(4)
+    kinds, T = slab.kinds, serve.step_tokens
+    assert slab.tokens.shape == slab.slot.shape == slab.pos.shape == (T,)
+    assert kinds[r0.slot] == 1 and kinds[r1.slot] == 4 and slab.lens[r1.slot] == 4
+    n = int(kinds.sum())
+    mine = lambda r: slab.slot[:n] == r.slot  # noqa: E731
+    assert slab.tokens[:n][mine(r0)].tolist() == [3]
+    assert slab.pos[:n][mine(r0)].tolist() == [4]
+    assert slab.tokens[:n][mine(r1)].tolist() == [9, 10, 11, 12]
+    assert slab.pos[:n][mine(r1)].tolist() == [4, 5, 6, 7]
+    assert (slab.pos[n:] == -1).all() and (slab.tokens[n:] == 0).all()
     idle = next(b for b in range(3) if s.slots[b] is None)
-    assert kinds[idle] == 0 and tables[idle].tolist() == [0] * tables.shape[1]
-    # dead rows of the decode slot route to the trash block, not its pages
-    pos = lens[:, None] + np.arange(4)[None]
-    valid = np.arange(4)[None] < kinds[:, None]
-    flat = np.asarray(
-        paged_flat_slots(
-            jnp.asarray(tables), jnp.asarray(pos), serve.block_size,
-            jnp.asarray(valid),
-        )
+    assert kinds[idle] == 0 and slab.tables[idle].tolist() == [0] * slab.tables.shape[1]
+    # live rows land in their slot's slab rows; padding rows write to the
+    # trash block, not to any slot's pages
+    flat, _ = slab_index(
+        *(jnp.asarray(x) for x in (slab.slot, slab.pos, slab.lens, kinds)), 4
     )
+    live = np.asarray(flat) < 3 * 4
+    assert live.tolist() == [True] * n + [False] * (T - n)
+    pool = np.asarray(
+        paged_flat_slots(
+            jnp.asarray(slab.tables[slab.slot]), jnp.asarray(slab.pos[:, None]),
+            serve.block_size, jnp.asarray(live[:, None]),
+        )
+    )[:, 0]
     bs = serve.block_size
-    assert (flat[r0.slot, 1:] < bs).all() and (flat[idle] < bs).all()
-    assert all(f // bs in r1.blocks for f in flat[r1.slot])
+    assert (pool[n:] < bs).all()
+    assert all(f // bs in r1.blocks for f in pool[:n][mine(r1)])
+    assert pool[:n][mine(r0)][0] // bs in r0.blocks
 
 
 def test_scheduler_rejects_oversized_request():

@@ -10,7 +10,9 @@ from repro.core.plan import (
     SPATIAL,
     TEMPORAL,
     derive_plan,
+    derive_serve_plan,
     design_case_vck5000,
+    serve_feasible,
 )
 from repro.core.pu import derive_pu_family, is_compute_bound, pick_pu, solve_mm_tiles
 
@@ -138,3 +140,38 @@ class TestDerivePlan:
         p2 = derive_plan(cfg, MESHES[0], batch=batch, seq_len=seq)
         assert p1 == p2  # pure function of its inputs
         assert batch % p1.microbatches == 0 or p1.microbatches == 1
+
+
+SERVABLE = [a for a in ALL_ARCHS if serve_feasible(get_config(a))[0]]
+
+
+class TestStepTokens:
+    """The mixed step's token budget T (``ServePlan.step_tokens``): derived
+    from B, W and gamma, never set by hand."""
+
+    def test_qwen3_on_v5e_is_1152(self):
+        sp = derive_serve_plan(get_config("qwen3-1.7b"), MESHES[2], TPU_V5E)
+        assert (sp.decode_batch, sp.mixed_slab_width, sp.spec_len) == (32, 256, 0)
+        # 32 decode rows + four 256-row chunks = 1056 -> the next multiple of 128
+        assert sp.step_tokens == 1152
+        assert sp.to_record()["step_tokens"] == 1152
+        assert "step_tokens=1152" in sp.describe()
+
+    @pytest.mark.parametrize("arch", SERVABLE)
+    @pytest.mark.parametrize("mesh", [MESHES[2], MESHES[0]], ids=["one", "multi"])
+    @pytest.mark.parametrize("draft", ["none", "ngram"])
+    def test_budget_holds_every_runner_and_a_chunk(self, arch, mesh, draft):
+        sp = derive_serve_plan(get_config(arch), mesh, TPU_V5E, draft=draft)
+        B, W, g, T = sp.decode_batch, sp.mixed_slab_width, sp.spec_len, sp.step_tokens
+        # every running slot's rows and four prefill chunks fit, except
+        # where the whole slab is smaller: no step can outgrow B x W
+        assert T >= min(B * (1 + g) + 4 * W, B * W)
+        assert T % 128 == 0 or T == B * W
+        assert T <= B * W
+
+    def test_small_slab_caps_the_budget(self):
+        cfg = get_config("smollm-135m").reduced()
+        sp = derive_serve_plan(
+            cfg, MESHES[2], max_seq_len=64, decode_batch=4, prefill_chunk=8
+        )
+        assert sp.step_tokens == 4 * 8  # the whole slab, not 128 rows

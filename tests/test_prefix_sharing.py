@@ -104,7 +104,7 @@ def test_scheduler_shares_blocks_and_skips_prefill():
     s.submit(r0)
     s.admit(0)
     while r0.state == "prefill":
-        _, _, _, kinds = s._slab_view(serve.mixed_slab_width)
+        kinds = s._slab_view(serve.mixed_slab_width).kinds
         s._slab_done(np.full((2,), 7, np.int64), kinds)
     assert r0.registered == 2  # base blocks indexed once resident
     r1 = Request(rid="b", prompt=base + [50, 51], max_new_tokens=4, arrival=0)

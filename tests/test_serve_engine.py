@@ -201,14 +201,14 @@ def _dense_cache_gathers(jaxpr, cache_len):
 def test_unified_step_jaxpr_has_no_dense_gather(key):
     """The acceptance bar of the fused kernel: no dense (B, cache_len, ...)
     gather is ever materialized inside the unified step — the only gathers
-    left are the (B, W)-sized embedding/table lookups.  The gather fallback
+    left are the stream-sized embedding/table lookups.  The gather fallback
     is the positive control: its jaxpr must show the dense buffer."""
     cfg, plan, serve, params = _setup(key)
-    B, W = serve.decode_batch, serve.mixed_slab_width
+    B, T = serve.decode_batch, serve.step_tokens
     args = (
         params,
         ServingEngine(params, cfg, plan, serve).pools,
-        jnp.zeros((B, W), jnp.int32),
+        jnp.zeros((3, T), jnp.int32),
         jnp.zeros((B, serve.max_blocks_per_seq), jnp.int32),
         jnp.zeros((B,), jnp.int32),
         jnp.ones((B,), jnp.int32),
@@ -287,6 +287,106 @@ def test_attn_row_counters_follow_the_packed_slab(key):
         mixed += bool((kinds == 1).any() and (kinds > 1).any())
     assert mixed  # some step carried decode and prefill slots together
     assert_traces_bounded(engine.trace_counts)
+
+
+def test_packed_step_keeps_parity_when_the_budget_binds(key):
+    """More prompt rows than the step's budget (14 slots x ~25 rows against
+    T = 128): the prompts are served over several steps, decode slots keep
+    decoding meanwhile, the counters see the budget bind, and every token
+    matches the eager oracle."""
+    cfg, plan, serve, params = _setup(
+        key, decode_batch=16, prefill_chunk=16, rolled_steps=1
+    )
+    assert serve.step_tokens == 128
+    rng = np.random.default_rng(11)
+    lengths = [30, 3, 28, 31, 6, 29, 30, 27, 26, 30, 25, 31, 22, 30, 12, 29]
+    prompts = [list(rng.integers(0, cfg.vocab_size, n)) for n in lengths]
+    reqs = [
+        Request(rid=f"t{i}", prompt=p, max_new_tokens=5, arrival=0 if i < 14 else 3)
+        for i, p in enumerate(prompts)
+    ]
+    engine = ServingEngine(params, cfg, plan, serve)
+    kinds = []
+    step = engine._step
+    engine._step = lambda *a: (kinds.append(np.asarray(a[5])), step(*a))[1]
+    got = engine.run(reqs)
+    for i, p in enumerate(prompts):
+        assert got[f"t{i}"] == _oracle(params, cfg, plan, p, 5), i
+    st = engine.stats
+    assert st["budget_bound_steps"] > 0 and st["prefill_rows_deferred"] > 0
+    assert st["rows_computed"] == st["steps"] * serve.step_tokens
+    assert all(int(k.sum()) <= serve.step_tokens for k in kinds)
+    # some step carried decode rows next to prompt chunks
+    assert any((k == 1).any() and (k > 1).any() for k in kinds)
+    assert engine.trace_counts == {"step": 1}
+
+
+def test_packed_step_compiles_once_for_every_live_row_count(key):
+    """One compile serves a stream of any number of live tokens, 0 to T,
+    spread over the slots: only the values of the stream change."""
+    from repro.serve.scheduler import pack
+
+    cfg, plan, serve, params = _setup(key, rolled_steps=1)
+    engine = ServingEngine(params, cfg, plan, serve)
+    B, W, T = serve.decode_batch, serve.mixed_slab_width, serve.step_tokens
+    assert T == B * W
+    table = np.zeros((B, serve.max_blocks_per_seq), np.int32)
+    zero = jnp.zeros((B,), jnp.float32)
+    for n in range(T + 1):
+        rows, left = {}, n
+        for b in range(B):
+            k = min(W, left)
+            rows[b] = (3 * b, list(range(1, k + 1)))
+            left -= k
+        slab = pack(rows, table, T)
+        assert int(slab.kinds.sum()) == n
+        tok, _, finite, engine.pools = engine._step(
+            params, engine.pools, slab.stream, slab.tables, slab.lens,
+            slab.kinds, zero,
+        )
+        assert bool(np.asarray(finite).all())
+    assert_traces_bounded(engine.trace_counts)
+    assert engine.trace_counts == {"step": 1}
+
+
+def test_budget_serves_runners_first_then_the_oldest_prefill(key):
+    """Host-only budget rules: every running slot gets its row (and its
+    drafts), prefilling slots share the rest oldest admitted first, a slot
+    the budget leaves out gets no rows and does not advance."""
+    from repro.serve.scheduler import RUNNING, Scheduler
+
+    cfg = get_config("smollm-135m").reduced()
+    serve = derive_serve_plan(
+        cfg, MESH1, max_seq_len=64, decode_batch=16, block_size=8,
+        kv_dtype="fp32", prefill_chunk=16,
+    )
+    T, W = serve.step_tokens, serve.mixed_slab_width
+    assert (T, W) == (128, 16)
+    s = Scheduler(serve)
+    reqs = [Request(rid=f"q{i}", prompt=[1 + i] * 40, max_new_tokens=4)
+            for i in range(16)]
+    for r in reqs:
+        s.submit(r)
+        s.admit(0)  # one at a time: admission order is t_admit order
+    runners = reqs[9:11]
+    for r in runners:
+        r.state, r.out, r.pos = RUNNING, [9], 40
+        s.lens[r.slot] = 40
+    s._grow_for_decode({runners[0].rid: 2})
+    slab = s._slab_view(W, {runners[0].rid: [5, 6]})
+    k = slab.kinds
+    assert k[runners[0].slot] == 3 and k[runners[1].slot] == 1
+    prefill = [r for r in reqs if r not in runners]  # admitted in this order
+    # 128 - 4 runner rows = 124 = 7 x 16 + 12, the rest get none
+    assert [int(k[r.slot]) for r in prefill] == [16] * 7 + [12] + [0] * 6
+    assert slab.deferred == 4 + 16 * 6
+    assert int(k.sum()) <= T and (slab.pos[int(k.sum()):] == -1).all()
+    left_out = prefill[-1]
+    assert slab.tables[left_out.slot].tolist() == [0] * serve.max_blocks_per_seq
+    s._slab_done(np.full((16,), 7, np.int64), k, drafts={runners[0].rid: [5, 6]},
+                 vtok=np.full((16, 3), 7, np.int64))
+    assert [r.pos for r in prefill] == [16] * 7 + [12] + [0] * 6
+    assert left_out.state == "prefill" and not left_out.out
 
 
 def test_plan_rolled_event_horizon_and_reservation(key):

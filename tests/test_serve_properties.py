@@ -126,7 +126,7 @@ def _host_step(s: Scheduler, rng) -> None:
     if not s.busy():
         return
     W = s.serve.mixed_slab_width
-    _tokens, _tables, _lens, kinds = s._slab_view(W)
+    kinds = s._slab_view(W).kinds
     sampled = rng.integers(0, 6, s.serve.decode_batch).astype(np.int32)
     s._slab_done(sampled, kinds)
 

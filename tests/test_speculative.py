@@ -374,3 +374,35 @@ def test_model_draft_caps_proposals_to_target_vocab(key):
     draft.target_vocab = 1  # pathological target: only token 0 verifiable
     out = draft.propose([("x", [0, 0, 0], 3)])
     assert all(t == 0 for t in out["x"])
+
+@pytest.mark.parametrize("source", ["ngram", "self"])
+def test_speculation_keeps_parity_when_the_budget_binds(key, source):
+    """Slots prefilling at once ask for more rows than the step's budget
+    (T = 128 < 12 x ~29 prompt rows): runners keep their gamma+1 rows,
+    prompts wait their turn, and the tokens still match the oracle — for
+    prompt lookup and for a model drafter whose own catch-up outgrows its
+    own budget."""
+    cfg, plan, serve, params = _setup(
+        key, decode_batch=16, prefill_chunk=16, spec_len=2, draft="ngram",
+        rolled_steps=1,
+    )
+    assert serve.spec_len == 2 and serve.step_tokens == 128
+    draft = NGramDraft() if source == "ngram" else _self_draft(cfg, serve, params)
+    prompts = _mixed_prompts(
+        cfg, seed=4, lengths=(30, 28, 31, 29, 30, 27, 26, 30, 29, 31, 28, 30, 25, 30)
+    )
+    reqs = [
+        Request(rid=f"b{i}", prompt=p, max_new_tokens=6, arrival=2 * (i // 12))
+        for i, p in enumerate(prompts)
+    ]
+    engine = ServingEngine(params, cfg, plan, serve, draft=draft)
+    got = engine.run(reqs)
+    for i, p in enumerate(prompts):
+        assert got[f"b{i}"] == _oracle(params, cfg, plan, p, 6), i
+    st = engine.stats
+    assert st["budget_bound_steps"] > 0 and st["prefill_rows_deferred"] > 0
+    assert st["spec_slots"] > 0
+    assert st["rows_computed"] == st["steps"] * serve.step_tokens
+    assert engine.trace_counts == {"step": 1}
+    if source == "self":
+        assert draft.trace_counts == {"draft_step": 1}

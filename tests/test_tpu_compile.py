@@ -104,9 +104,14 @@ def test_paged_attention_compiles_at_derived_tile(
 
 
 def test_unified_step_fits_one_chip(one_chip, compiled_kernels):
-    """The engine's mixed step at the derived plan: Mosaic kernel inside,
-    and weights + KV pool + the step's working set within the HBM that
-    XLA:TPU gives a program (it raises RESOURCE_EXHAUSTED otherwise)."""
+    """The engine's mixed step at the derived plan, on its packed stream of
+    ``step_tokens`` rows: Mosaic kernel inside, and weights + KV pool + the
+    step's working set within the HBM that XLA:TPU gives a program (it
+    raises RESOURCE_EXHAUSTED otherwise).  The temporaries are XLA:TPU's
+    bf16 copies of the f32 layer weights (hoisted out of the layer loop,
+    2 B a non-embedding parameter) and the step's working set, which
+    follows T: 0.23 GB past the copies, against 0.54 GB for the whole
+    (B, W) slab."""
     from repro.models.cache import init_paged_cache
     from repro.models.params import init_params
     from repro.serve.engine import make_mixed_step
@@ -120,15 +125,22 @@ def test_unified_step_fits_one_chip(one_chip, compiled_kernels):
 
     params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg, plan, dtype=SERVE_WEIGHT_DTYPE)))
     pools = place(jax.eval_shape(lambda: init_paged_cache(cfg, plan, serve)))
-    B, W, MB = serve.decode_batch, serve.mixed_slab_width, serve.max_blocks_per_seq
+    B, T, MB = serve.decode_batch, serve.step_tokens, serve.max_blocks_per_seq
+    assert T == 1152
     S = lambda shape, dt: _sds(one_chip, shape, dt)
     step = make_mixed_step(cfg, plan, serve, fused=True)
     compiled = step.lower(
-        params, pools, S((B, W), jnp.int32), S((B, MB), jnp.int32),
+        params, pools, S((3, T), jnp.int32), S((B, MB), jnp.int32),
         S((B,), jnp.int32), S((B,), jnp.int32), S((B,), jnp.float32),
     ).compile()
-    assert _names_kernel(compiled.as_text())
+    hlo = compiled.as_text()
+    assert _names_kernel(hlo)
+    # the paged kernel is the step's only custom call
+    assert sum("tpu_custom_call" in line and "custom-call(" in line
+               for line in hlo.splitlines()) == 1
     m = compiled.memory_analysis()
+    bf16_copies = 2 * (cfg.param_count() - cfg.vocab_size * cfg.d_model)
+    assert m.temp_size_in_bytes - bf16_copies < 0.3e9
     # the donated pools come back in place: only the sampled tokens are new
     assert m.output_size_in_bytes - m.alias_size_in_bytes < 2**20
     # 15.75 of the chip's 16 GiB are the program's
