@@ -10,7 +10,7 @@ feasibility models ``derive_serve_plan`` uses, cost every candidate on three
 axes (tokens/s, $/token, J/token), and keep the Pareto frontier.  Each
 frontier point carries its full :class:`~repro.core.plan.ServePlan` plus the
 autotune-resolved MM tile for its dominant GEMM site, so a point is directly
-runnable by the serving engine (benchmarks/family_search.py replays one).
+runnable by the serving engine (``launch/serve.py --from-family``).
 
 Everything here is pure host arithmetic — no jax, no compilation — so a full
 sweep over a few hundred candidates is milliseconds.  The cost model and
@@ -56,8 +56,8 @@ class SearchSpace:
     draft: str = "ngram"  # source used whenever a candidate speculates
     # Modeled per-row draft acceptance probability (alpha).  Expected
     # accepted tokens per slot per step is (1 - a^(g+1)) / (1 - a) — the
-    # standard speculative-decoding expectation; 0.6 matches the NGram
-    # draft's measured mid-range on BENCH_spec.json.
+    # standard speculative-decoding expectation; 0.6 is an assumed
+    # mid-range for the NGram draft.
     acceptance: float = 0.6
 
 

@@ -18,7 +18,6 @@ from repro.dist.collectives import (
     overlap_all_gather_matmul,
     ring_gather_matmul,
     seq_scatter,
-    wire_bytes,
 )
 from repro.dist.pipeline import bubble_fraction, pipeline_forward
 from repro.dist.sharding import Shardings
@@ -29,7 +28,6 @@ __all__ = [
     "ring_gather_matmul",
     "seq_scatter",
     "compressed_psum",
-    "wire_bytes",
     "bubble_fraction",
     "pipeline_forward",
 ]

@@ -126,13 +126,3 @@ def compressed_psum(g, axis, mode: str = "int8"):
         q, _ = quantize(g32, mode, scale=scale)
         return lax.psum(q.astype(jnp.int32), axis).astype(jnp.float32) * scale
     return lax.psum(g, axis)
-
-
-def wire_bytes(n_elements: int, mode: str) -> int:
-    """Bytes one replica puts on the wire per exchange for ``n_elements``
-    gradient values (the quantity BENCH_dist.json tracks).  int8 counts the
-    ideal narrow-wire payload (1 byte + amortized scale), the format the
-    schedule is designed for, not the int32 words the current backend moves.
-    """
-    per = {"bf16": 2, "int8": 1}.get(mode, 4)
-    return n_elements * per + (4 if mode == "int8" else 0)

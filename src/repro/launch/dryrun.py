@@ -9,21 +9,17 @@ Usage:
     # and print/write the Pareto frontier (tokens/s vs $/token vs J/token)
     PYTHONPATH=src python -m repro.launch.dryrun --family --hardware tpu_v5e
 
-Results go to benchmarks/results/dryrun/<mesh>/<arch>__<shape>.json
+Results go to dryrun_out/<mesh>/<arch>__<shape>.json at the checkout root
 (incremental: existing cells are skipped unless --force); --family reports
-go to benchmarks/results/family/<hardware>__<arch>.json.
+go to dryrun_out/family/<hardware>__<arch>.json.
 """
 # The dry-run (and ONLY the dry-run) needs 512 placeholder devices; this must
-# run before ANY other import that touches jax.  --calibrate actually *runs*
-# a serving stream, so it keeps the real device topology instead.
+# run before ANY other import that touches jax.
 import os
-import sys
 
-if "--calibrate" not in sys.argv:
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=512 "
-        + os.environ.get("XLA_FLAGS", "")
-    )
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
+)
 
 import argparse
 import json
@@ -47,7 +43,7 @@ from repro.serve.engine import make_decode_step, make_prefill_step
 from repro.train.optimizer import OptimizerConfig
 from repro.train.train_step import make_train_step
 
-RESULTS = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
+RESULTS = pathlib.Path(__file__).resolve().parents[3] / "dryrun_out"
 
 
 def input_specs(cfg, shape, plan):
@@ -275,63 +271,20 @@ def main():
              "repro.core.hardware.registered_hardware)",
     )
     ap.add_argument(
-        "--calibrate", action="store_true",
-        help="run a short serving stream on the real backend and print the "
-             "planner drift report: predicted (roofline) vs measured wall "
-             "time per phase (docs/OBSERVABILITY.md §Drift)",
-    )
-    ap.add_argument(
         "--max-seq", type=int, default=2048,
         help="serving context bound for the --family sweep",
     )
     ap.add_argument(
         "--bench-out", default=None,
-        help="write an aggregate JSON of all cells run (CI benchmark artifact)",
+        help="write an aggregate JSON of all cells run",
     )
     a = ap.parse_args()
-
-    if a.calibrate:
-        from repro.launch.mesh import make_host_mesh
-        from repro.launch.serve import ServeArgs, run_batched
-
-        arch = a.arch or "smollm-135m"
-        cfg = get_config(arch)
-        ok, reason = serve_feasible(cfg)
-        if not ok:
-            raise SystemExit(f"{arch}: {reason} (pick a serve-feasible --arch)")
-        sargs = ServeArgs(
-            arch=arch, requests=6, prompt_len=16, gen=12, stagger=2,
-            max_seq=96, batch=3, fix_batch=True, prefill_chunk=16,
-            hardware=a.hardware,
-        )
-        cal = run_batched(sargs, cfg, make_host_mesh()).summary()["calibration"]
-        print()
-        print(f"planner drift calibration ({arch}, roofline priced as {a.hardware}):")
-        for phase, rep in (cal.get("phases") or {}).items():
-            if rep is None:
-                continue
-            print(
-                f"  {phase:8s} n={rep['n']:4d}"
-                f" predicted={rep['predicted_ms_mean']:8.3f}ms"
-                f" measured={rep['measured_ms_mean']:8.3f}ms"
-                f" ratio={rep['ratio']:8.2f}"
-                f" p50={rep['ratio_p50']:8.2f} p90={rep['ratio_p90']:8.2f}"
-            )
-        print(f"  overall ratio: {cal.get('overall_ratio')}")
-        print(f"  {cal.get('note')}")
-        if a.bench_out:
-            pathlib.Path(a.bench_out).write_text(
-                json.dumps({"arch": arch, "hardware": a.hardware,
-                            "calibration": cal}, indent=1, default=str)
-            )
-            print(f"wrote {a.bench_out}")
-        return
 
     if a.family:
         from repro.core.search import family_report
 
         arch = a.arch or "qwen3-1.7b"
-        out_dir = RESULTS.parent / "family"
+        out_dir = RESULTS / "family"
         result, record = family_report(
             arch, a.hardware, max_seq_len=a.max_seq, out_dir=out_dir,
         )

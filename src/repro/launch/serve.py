@@ -191,7 +191,7 @@ class ServeArgs:
         )
 
     def make_observability(self):
-        """The engine's observability bundle: metrics + drift always on,
+        """The engine's observability bundle: metrics always on,
         lifecycle tracing only when ``--trace-out`` asks for the export."""
         from repro.obs import Observability
 
@@ -307,9 +307,6 @@ def run_batched(a: ServeArgs, cfg, mesh):
             with open(a.metrics_out, "w") as f:
                 json.dump(obs.metrics.snapshot(), f, indent=1)
         print(f"wrote metrics to {a.metrics_out}")
-    cal = summary["calibration"]
-    if cal.get("overall_ratio"):
-        print(f"planner calibration: {cal['note']}")
     print(json.dumps(summary, indent=1, default=str))
     return engine
 

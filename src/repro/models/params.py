@@ -183,7 +183,3 @@ def init_params(
             "final_norm": _norm_params(cfg, cfg.d_model),
         }
     return params
-
-
-def param_count_tree(params: PyTree) -> int:
-    return sum(x.size for x in jax.tree.leaves(params))

@@ -1,6 +1,6 @@
 """``repro.obs`` — unified serving observability.
 
-Three layers, one bundle:
+Two layers, one bundle:
 
 * :class:`~repro.obs.metrics.MetricsRegistry` — counters / gauges /
   fixed-bucket histograms with declared labels; Prometheus text exposition
@@ -10,15 +10,12 @@ Three layers, one bundle:
   JSON for Perfetto (``--trace-out`` / ``--trace-buffer``), and
   :class:`~repro.obs.trace.Phases`, the engine step's phases, mirrored
   into the profiler's trace (``jax.profiler.TraceAnnotation``) and timed
-  into ``engine.stats`` (``admit_s`` ... ``harvest_s``);
-* :class:`~repro.obs.calibrate.DriftMeter` — predicted (roofline) vs
-  measured wall time per dispatch, per phase — the planner's calibration
-  signal (``engine.summary()["calibration"]``, ``dryrun --calibrate``).
+  into ``engine.stats`` (``admit_s`` ... ``harvest_s``).
 
 :class:`Observability` is the bundle the engine, scheduler, draft sources
 and fault injector all emit into.  The default construction
 (``Observability()``) is what every engine gets when the caller passes
-nothing: metrics + drift on (pure host dict arithmetic), tracing *off* —
+nothing: metrics on (pure host dict arithmetic), tracing *off* —
 the disabled tracer returns before touching its ring, so the engine hot
 path is unchanged (no extra device dispatches; the parity matrix asserts
 byte-identical output and identical ``trace_counts`` with tracing on).
@@ -30,7 +27,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.obs.calibrate import DriftMeter, StepTimeModel, step_time_model
 from repro.obs.metrics import (
     DEFAULT_MS_BUCKETS,
     Counter,
@@ -68,7 +64,6 @@ class Observability:
         trace_buffer: int = 65536,
         metrics: Optional[MetricsRegistry] = None,
         tracer: Optional[Tracer] = None,
-        drift: Optional[DriftMeter] = None,
     ):
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = (
@@ -76,7 +71,6 @@ class Observability:
             if tracer is not None
             else Tracer(trace_buffer, enabled=tracing)
         )
-        self.drift = drift if drift is not None else DriftMeter()
         m = self.metrics
         # ---- the serving metric catalog (docs/OBSERVABILITY.md) ----------
         self.m_submitted = m.counter(
@@ -241,24 +235,16 @@ class Observability:
         composition: Optional[dict] = None,
         rung: str = "",
         k: int = 1,
-        predicted_s: Optional[float] = None,
-        calibrated: bool = True,
     ) -> None:
         """One device dispatch (a step, a rolled span, or the gather
-        fallback).  ``calibrated=False`` (a compile iteration) records the
-        step metric but keeps the drift meter clean."""
+        fallback)."""
         measured_s = t1 - t0
         self.m_steps.inc(kind=kind)
         self.m_step_ms.observe(measured_s * 1e3, phase=phase)
-        if calibrated and predicted_s is not None:
-            self.drift.record(phase, predicted_s, measured_s)
         args = {
             "phase": phase, "rows": rows, "rung": rung, "k": k,
             "measured_ms": measured_s * 1e3,
         }
-        if predicted_s is not None:
-            args["predicted_ms"] = predicted_s * 1e3
-            args["calibrated"] = bool(calibrated)
         if composition:
             args["kinds"] = dict(composition)
         self.tracer.complete(kind, PID_ENGINE, TID_DISPATCH, t0, t1, args)
@@ -343,8 +329,4 @@ __all__ = [
     "TID_DISPATCH",
     "TID_FAULTS",
     "TID_PHASES",
-    # calibration layer
-    "DriftMeter",
-    "StepTimeModel",
-    "step_time_model",
 ]

@@ -21,7 +21,7 @@ The documented surface is deliberately small:
   :class:`LadderExhausted`) — the chaos harness and the exceptions the
   hardened engine raises (see ``docs/ROBUSTNESS.md``).
 * :class:`Observability` (re-exported from :mod:`repro.obs`) — the
-  metrics + tracing + drift bundle the engine accepts via
+  metrics + tracing bundle the engine accepts via
   ``ServingEngine(..., obs=)`` (see ``docs/OBSERVABILITY.md``).
 
 Everything else (``Scheduler``, ``BlockAllocator``, ``PrefixIndex``,

@@ -45,7 +45,6 @@ from repro.models.cache import (
 )
 from repro.models.transformer import forward, logits_fn, slab_index
 from repro.obs import Observability, Phases
-from repro.obs.calibrate import step_time_model
 from repro.serve.faults import (
     LADDER,
     SALTS,
@@ -411,8 +410,8 @@ class ServingEngine:
         if not ok:
             raise ValueError(f"{cfg.name} cannot serve continuously: {reason}")
         self.cfg, self.plan, self.serve = cfg, plan, serve
-        # observability bundle: metrics + drift meter always on (pure host
-        # arithmetic), lifecycle tracing only when the caller enabled it on
+        # observability bundle: metrics always on (pure host arithmetic),
+        # lifecycle tracing only when the caller enabled it on
         # the bundle — emission can never touch shapes or device work
         self.obs = obs if obs is not None else Observability()
         self.hw = hw if hw is not None else resolve_hardware(DEFAULT_HARDWARE.name)
@@ -443,18 +442,6 @@ class ServingEngine:
                 shardings is None or shardings.mesh.size == 1
             )
         self.fused = bool(fused)
-        # planner drift meter: freeze the predict_point roofline constants
-        # for this (arch, plan, device, TP degree) so pricing a dispatch is
-        # O(1); every calibrated dispatch records predicted vs measured
-        # (summary()["calibration"], docs/OBSERVABILITY.md §Drift meter)
-        mesh_model = (
-            dict(shardings.mesh.shape).get("model", 1)
-            if shardings is not None
-            else 1
-        )
-        self._cost = step_time_model(
-            cfg, serve, self.hw, mesh_model=mesh_model, fused=self.fused
-        )
         self.draft = draft
         if draft is not None and hasattr(draft, "bind_obs"):
             draft.bind_obs(self.obs)
@@ -750,7 +737,7 @@ class ServingEngine:
         if s.busy():
             slab = s._slab_view(self.serve.mixed_slab_width, drafts)
             kinds = slab.kinds
-            # slab composition + roofline price, snapshotted pre-dispatch
+            # slab composition, snapshotted pre-dispatch
             # (``_slab_done`` mutates slot states); a prefilling slot the
             # budget left out counts as idle this step
             composition = {
@@ -761,9 +748,6 @@ class ServingEngine:
             }
             rows = int(kinds.sum())
             phase = "prefill" if composition["prefill"] else "decode"
-            predicted_s = self._cost.predict_s(
-                rows, float(slab.lens.sum()) + rows
-            )
             while not self._retry_transients():
                 if not self._escalate():
                     raise LadderExhausted(
@@ -790,8 +774,8 @@ class ServingEngine:
             t1 = ph.to("engine.harvest")
             dt_ms = (t1 - t0) * 1e3
             self._note_healthy()
-            calibrated = self.trace_counts[trace_key] == traces_before
-            if calibrated:
+            compiled_now = self.trace_counts[trace_key] != traces_before
+            if not compiled_now:
                 # feed SLO chunk sizing a compile-free step-time estimate
                 s.step_ms = (
                     dt_ms if s.step_ms is None else 0.8 * s.step_ms + 0.2 * dt_ms
@@ -799,7 +783,6 @@ class ServingEngine:
             self.obs.on_dispatch(
                 trace_key, phase, t0, t1, rows=rows,
                 composition=composition, rung=LADDER[self.rung],
-                predicted_s=predicted_s, calibrated=calibrated,
             )
             c = s._slab_done(
                 sampled, kinds, vtok, drafts, finite=finite, span=(t0, t1)
@@ -878,8 +861,8 @@ class ServingEngine:
         dt_ms = (t1 - t0) * 1e3
         self._note_healthy()
         adv = int(steps.max())  # device iterations actually executed
-        calibrated = self.trace_counts["rolled_step"] == traces_before and adv > 0
-        if calibrated:
+        compiled_now = self.trace_counts["rolled_step"] != traces_before
+        if not compiled_now and adv > 0:
             # per-iteration estimate feeds the same SLO chunk-sizing EMA
             per = dt_ms / adv
             s.step_ms = per if s.step_ms is None else 0.8 * s.step_ms + 0.2 * per
@@ -890,10 +873,6 @@ class ServingEngine:
                 "idle": self.serve.decode_batch - live, "decode": live,
             },
             rung=LADDER[self.rung], k=adv,
-            predicted_s=self._cost.predict_s(
-                live, float(np.asarray(s.lens).sum()), k=max(adv, 1)
-            ),
-            calibrated=calibrated,
         )
         c = s._rolled_done(out, steps, span=(t0, t1))
         self.obs.on_step_counts(c)
@@ -1217,10 +1196,6 @@ class ServingEngine:
                 "peak_blocks": self.sched.alloc.peak_in_use,
                 "double_frees": self.sched.alloc.double_frees,
             },
-            # planner drift meter (obs/calibrate.py): measured dispatch wall
-            # time vs the predict_point roofline, per phase — the signal
-            # that explains whether modeled orderings survive this backend
-            "calibration": self.obs.drift.report(),
             "spec": {
                 "enabled": spec_on,
                 "spec_len": self.spec_len,

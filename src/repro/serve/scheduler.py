@@ -67,7 +67,7 @@ def random_stream(
     seed: int = 0,
     rid_prefix: str = "req",
 ) -> list["Request"]:
-    """Synthetic staggered request stream (launcher, benchmarks, examples all
+    """Synthetic staggered request stream (launcher, tests and examples all
     share this so they exercise the same arrival semantics).
 
     ``prompt_len`` is an int for fixed-length prompts or an (lo, hi) tuple

@@ -299,7 +299,7 @@ def make_draft_source(
     else is a config name -> :class:`ModelDraft` with freshly initialized
     params (or ``params`` when the caller already has trained weights —
     passing the *target's* params turns it into a self-drafting oracle,
-    useful as the acceptance upper bound in benchmarks)."""
+    useful as the acceptance upper bound)."""
     if name in (None, "", "none"):
         return None
     if name == "ngram":

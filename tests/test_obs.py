@@ -1,22 +1,19 @@
-"""Unified observability: metrics registry, lifecycle tracing, drift meter.
+"""Unified observability: metrics registry and lifecycle tracing.
 
 Three groups:
 
 * **unit** — registry semantics (labels, histograms, Prometheus round-trip
   as an exact parse-of-exposition == flat-samples oracle), tracer ring
-  buffer + Chrome trace_event structure, drift-meter arithmetic.  Pure
-  host code, no jax.
+  buffer + Chrome trace_event structure.  Pure host code, no jax.
 * **engine integration** — one real serving run with the full bundle on:
   golden Chrome-trace validity (monotone timestamps, >= 1 complete request
   lifecycle nested under step spans), metric/summary back-compat
-  agreement, finite calibration for both phases, and the disabled-mode
-  no-op guarantee (tracing off leaves the ring empty).
+  agreement, and the disabled-mode no-op guarantee (tracing off leaves the ring empty).
 * **launcher** — ``--replay-trace`` is the canonical replay spelling and
   ``--trace`` keeps working as a deprecation alias (both spellings, plus
   the conflict error).
 """
 import json
-import math
 
 import jax.numpy as jnp
 import pytest
@@ -24,7 +21,6 @@ import pytest
 from conftest import assert_traces_bounded
 
 from repro.configs import get_config
-from repro.core.hardware import TPU_V5E
 from repro.core.plan import derive_plan, derive_serve_plan
 from repro.obs import (
     PID_ENGINE,
@@ -35,7 +31,6 @@ from repro.obs import (
     prometheus_roundtrip_ok,
     validate_chrome_trace,
 )
-from repro.obs.calibrate import DriftMeter, step_time_model
 from repro.obs.metrics import MetricsRegistry
 from repro.serve import ServingEngine
 from repro.serve.scheduler import Request, random_stream
@@ -106,35 +101,6 @@ def test_tracer_disabled_is_noop():
     tr.complete("y", PID_ENGINE, 0, 1.0, 2.0)
     tr.request_span("z", "r0", 1.0, 2.0)
     assert len(validate_chrome_trace(tr.chrome_trace())) == 0
-
-
-# ------------------------------------------------------------ drift meter
-def test_drift_meter_report_arithmetic():
-    dm = DriftMeter()
-    assert dm.empty
-    for _ in range(4):
-        dm.record("decode", predicted_s=0.001, measured_s=0.002)
-    dm.record("prefill", predicted_s=0.002, measured_s=0.001)
-    rep = dm.report()
-    assert rep["phases"]["decode"]["ratio"] == pytest.approx(2.0)
-    assert rep["phases"]["prefill"]["ratio"] == pytest.approx(0.5)
-    # aggregate over total time, not mean-of-ratios
-    assert rep["overall_ratio"] == pytest.approx(9.0 / 6.0)
-    assert "roofline" in rep["note"]
-
-
-def test_step_time_model_scales_with_rows_and_k():
-    cfg = get_config("smollm-135m").reduced()
-    serve = derive_serve_plan(cfg, MESH1, TPU_V5E, max_seq_len=64)
-    m = step_time_model(cfg, serve, TPU_V5E)
-    one = m.predict_s(1, 64)
-    assert math.isfinite(one) and one > 0
-    # k iterations pay k rooflines but ONE dispatch overhead
-    k4 = m.predict_s(1, 64, k=4)
-    assert k4 < 4 * one
-    assert k4 > m.predict_s(1, 64, k=1)
-    # more resident context -> more KV bytes -> no cheaper
-    assert m.predict_s(1, 4096) >= one
 
 
 # ---------------------------------------------------- engine integration
@@ -210,25 +176,11 @@ def test_metrics_agree_with_summary(obs_run):
     assert prometheus_roundtrip_ok(obs.metrics)
 
 
-def test_calibration_finite_for_both_phases(obs_run):
-    engine, _, obs, _, _ = obs_run
-    cal = engine.summary()["calibration"]
-    for phase in ("prefill", "decode"):
-        rep = cal["phases"].get(phase)
-        assert rep is not None, f"no {phase} dispatches calibrated"
-        assert rep["n"] >= 1
-        for k, v in rep.items():
-            assert v is not None and math.isfinite(v), (phase, k, v)
-    assert math.isfinite(cal["overall_ratio"]) and cal["overall_ratio"] > 0
-    assert cal["note"]
-
-
 def test_default_obs_keeps_tracing_off(obs_run):
     _, plain, _, _, _ = obs_run
-    # the default bundle: metrics + drift on, tracer disabled and EMPTY
+    # the default bundle: metrics on, tracer disabled and EMPTY
     assert plain.obs.tracer.enabled is False
     assert len(validate_chrome_trace(plain.obs.tracer.chrome_trace())) == 0
-    assert not plain.obs.drift.empty  # drift still accumulated
 
 
 PHASES = ("engine.admit", "engine.pack", "engine.launch", "engine.sync",

@@ -16,40 +16,41 @@ from repro.core.search import (
 )
 
 
-@pytest.fixture(scope="module")
-def v5e_family():
-    return search_family("qwen3-1.7b", get_hardware("tpu_v5e"))
+@pytest.fixture(scope="module", params=["tpu_v5e", "vck5000"])
+def family(request):
+    return search_family("qwen3-1.7b", get_hardware(request.param))
 
 
 # ---------------------------------------------------------------- frontier
-def test_every_frontier_point_is_feasible(v5e_family):
+def test_every_frontier_point_is_feasible(family):
     ok, reason = serve_feasible(get_config("qwen3-1.7b"))
     assert ok, reason
-    assert v5e_family.frontier
-    for p in v5e_family.frontier:
+    assert family.frontier
+    for p in family.frontier:
         assert p.feasible, p.reason
         assert p.tokens_per_s > 0
         assert p.step_s > 0
 
 
-def test_no_dominated_point_on_frontier(v5e_family):
-    for p in v5e_family.frontier:
+def test_no_dominated_point_on_frontier(family):
+    for p in family.frontier:
         assert not any(
-            dominates(q, p) for q in v5e_family.frontier if q is not p
+            dominates(q, p) for q in family.frontier if q is not p
         )
 
 
-def test_frontier_meets_acceptance_floor(v5e_family):
+def test_frontier_meets_acceptance_floor():
     # the --family acceptance bar: >= 3 non-dominated points on tpu_v5e
+    v5e_family = search_family("qwen3-1.7b", get_hardware("tpu_v5e"))
     assert len(v5e_family.frontier) >= 3
 
 
-def test_search_is_deterministic(v5e_family):
-    again = search_family("qwen3-1.7b", get_hardware("tpu_v5e"))
-    assert [p.to_record() for p in v5e_family.points] == [
+def test_search_is_deterministic(family):
+    again = search_family("qwen3-1.7b", get_hardware(family.hardware))
+    assert [p.to_record() for p in family.points] == [
         p.to_record() for p in again.points
     ]
-    assert [p.to_record() for p in v5e_family.frontier] == [
+    assert [p.to_record() for p in family.frontier] == [
         p.to_record() for p in again.frontier
     ]
 

@@ -85,3 +85,30 @@ def test_sharded_dryrun_subprocess():
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["flops"] > 0
     assert out["n_coll"] > 0  # sharded training must communicate
+
+
+_DRYRUN_CELL_SNIPPET = """
+import json, sys
+from repro.configs import ALL_SHAPES
+from repro.launch.dryrun import run_cell
+
+shape = next(s for s in ALL_SHAPES if s.name == "train_4k")
+r = run_cell("smollm-135m", shape, multi_pod=False, force=True, out_dir=sys.argv[1])
+print(json.dumps({k: r.get(k) for k in ("status", "mesh", "error")}))
+"""
+
+
+def test_dryrun_cell_on_production_mesh_subprocess(tmp_path):
+    """The dry-run CLI's cell path on its 256-chip single-pod mesh: the
+    smollm-135m train_4k cell compiles and its record is written."""
+    r = subprocess.run(
+        [sys.executable, "-c", _DRYRUN_CELL_SNIPPET, str(tmp_path)],
+        capture_output=True, text=True, timeout=540,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"},
+        cwd=".",
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out == {"status": "ok", "mesh": "single", "error": None}
+    rec = json.loads((tmp_path / "single" / "smollm-135m__train_4k.json").read_text())
+    assert rec["status"] == "ok"
