@@ -672,12 +672,19 @@ def derive_serve_plan(
       compute-bound chunk).  The step's dense layers run a packed stream
       of ``ServePlan.step_tokens`` rows, derived from the batch, this width
       and gamma, so a decode slot costs one row, not W.
-    * **pages per VMEM tile** — the largest block-table divisor at which
-      the fused kernel's whole footprint for one grid step (q and output
-      blocks, the softmax carry, the score arrays and the page tile with
-      its in-kernel copies: ``kernels/paged_attention/kernel.vmem_bytes``)
-      fits an eighth of the chip's VMEM, Mosaic's default scoped limit
-      (16 of 128 MiB on v5e).
+    * **pages per kernel tile** — the kernel sweeps a slot's context in
+      tiles of ``pages_per_tile * block_size`` keys, and each tile costs a
+      fixed share of loop, DMA issue and wait on top of its keys' work.
+      The tile is the smallest block-table divisor of at least
+      ``hw.mxu_dim`` keys (a tile then fills the score arrays' 128 lanes
+      and the depth of the p·V contraction), never past the largest
+      divisor whose whole kernel footprint
+      (``kernels/paged_attention/kernel.vmem_bytes``: q and output blocks,
+      the softmax carry, the score arrays, the double-buffered page tiles
+      and their in-kernel copies) fits half the chip's VMEM.  The kernel
+      asks Mosaic for that footprint and a quarter more
+      (``kernel.vmem_limit``), so half leaves the limit well inside the
+      chip's VMEM (64 of 128 MiB on v5e).
     * **rolled decode steps (K cap)** — how many decode iterations one host
       dispatch should carry.  A dispatch costs ``hw.dispatch_overhead_s``
       no matter how much it launches, while one decode step is
@@ -807,10 +814,13 @@ def derive_serve_plan(
                 pages_per_tile=ppt,
             )
 
-        pages_per_tile = 1
-        for ppt in range(1, max_blocks_per_seq + 1):
-            if max_blocks_per_seq % ppt == 0 and footprint(ppt) <= hw.vmem_bytes // 8:
-                pages_per_tile = ppt
+        fits = [
+            ppt for ppt in range(1, max_blocks_per_seq + 1)
+            if max_blocks_per_seq % ppt == 0 and footprint(ppt) <= hw.vmem_bytes // 2
+        ] or [1]
+        pages_per_tile = next(
+            (ppt for ppt in fits if ppt * block_size >= hw.mxu_dim), fits[-1]
+        )
     if rolled_steps is None:
         # Dispatch-overhead slack: one decode step streams the weights once
         # (est_step_s); the host round-trip costs dispatch_overhead_s on

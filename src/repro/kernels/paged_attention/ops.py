@@ -1,7 +1,7 @@
 """Jitted public wrapper: model layout -> kernel layout for paged attention.
 
 ``pages_per_tile`` is the plan knob (``ServePlan.pages_per_tile``, derived
-from the hardware model's VMEM budget in ``core/plan.derive_serve_plan``);
+from the MXU width and the VMEM budget in ``core/plan.derive_serve_plan``);
 it is clamped here to a divisor of the table width so the tile sweep covers
 the row exactly.
 """

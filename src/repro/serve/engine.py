@@ -36,8 +36,8 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.hardware import DEFAULT_HARDWARE, HardwareSpec, resolve_hardware
-from repro.core.plan import ExecutionPlan, ServePlan, serve_feasible
-from repro.kernels.paged_attention.kernel import rows_computed
+from repro.core.plan import ExecutionPlan, ServePlan, largest_divisor_of, serve_feasible
+from repro.kernels.paged_attention.kernel import live_tiles, rows_computed
 from repro.models.cache import (
     cache_from_prefill,
     init_paged_cache,
@@ -442,6 +442,17 @@ class ServingEngine:
                 shardings is None or shardings.mesh.size == 1
             )
         self.fused = bool(fused)
+        # the kernel's table tile as ``kernels/paged_attention/ops`` sizes
+        # it, and the attention window (every servable model's attention
+        # layers share one), for the tile counters
+        mb = serve.max_blocks_per_seq
+        ppt = largest_divisor_of(mb, serve.pages_per_tile or mb)
+        layer_kinds = {cfg.layer_kind(i) for i in range(cfg.n_layers)}
+        window = (
+            cfg.sliding_window if "swa" in layer_kinds
+            else cfg.local_window if "local" in layer_kinds else 0
+        )
+        self._sweep = {"tile": ppt * serve.block_size, "n_tiles": mb // ppt, "window": window}
         self.draft = draft
         if draft is not None and hasattr(draft, "bind_obs"):
             draft.bind_obs(self.obs)
@@ -464,8 +475,11 @@ class ServingEngine:
             "admit_s": 0.0, "pack_s": 0.0, "launch_s": 0.0, "sync_s": 0.0,
             "harvest_s": 0.0,
             # query rows per KV head and layer the paged kernel computes on
-            # a live tile, and those carrying a token (``_count_attn_rows``)
+            # a live tile, and those carrying a token; the (slot, tile)
+            # sweeps it makes a layer, the keys those tiles fetch and the
+            # keys the live rows attend (``_count_attn_rows``)
             "attn_rows": 0, "attn_live_rows": 0,
+            "attn_tiles": 0, "attn_keys_fetched": 0, "attn_live_keys": 0,
             # rows the dense layers run (``step_tokens`` a mixed step, B a
             # rolled iteration), and how often the step's token budget left
             # a prefilling slot short, by how many rows
@@ -808,7 +822,7 @@ class ServingEngine:
             self.stats["budget_bound_steps"] += int(slab.deferred > 0)
             self.stats["prefill_rows_deferred"] += slab.deferred
             if self.fused and trace_key == "step":
-                self._count_attn_rows(kinds, self.serve.mixed_slab_width)
+                self._count_attn_rows(slab.lens, kinds, self.serve.mixed_slab_width)
         self.iteration += 1
 
     def _rolled_dispatch(self, k: int, steps: np.ndarray) -> bool:
@@ -844,6 +858,7 @@ class ServingEngine:
                     iteration=self.iteration, slots=n, span_k=int(k),
                 )
         traces_before = self.trace_counts["rolled_step"]
+        lens0 = np.array(s.lens)
         t0 = ph.to("engine.launch")
         if self.injector is not None:
             sp = self.injector.spike_s(self.iteration)
@@ -891,19 +906,34 @@ class ServingEngine:
         self.stats["occupancy_sum"] += float(steps.sum()) / self.serve.decode_batch
         self.stats["rows_computed"] += self.serve.decode_batch * adv
         if self.fused:
-            self._count_attn_rows(np.asarray(steps) > 0, 1, reps=steps)
+            # iteration j of the span: slot b decodes at lens0[b] + j while
+            # j < steps[b]
+            j = np.arange(adv)[:, None]
+            self._count_attn_rows(lens0[None] + j, np.asarray(steps)[None] > j, 1)
         self.iteration += adv
         return True
 
-    def _count_attn_rows(self, q_lens, width: int, reps=1) -> None:
-        """Add a dispatch's rows to ``attn_rows`` (rows per KV head and layer
-        the paged kernel computes on a live tile, by the kernel's own rule)
-        and ``attn_live_rows`` (G per live query): slots with ``q_lens``
-        live queries in a slab of ``width``, each ``reps`` times."""
+    def _count_attn_rows(self, lens, q_lens, width: int) -> None:
+        """Add one layer's kernel work over dispatched slots with ``lens``
+        cached positions and ``q_lens`` live queries in a slab of
+        ``width`` (arrays of any shape, one entry a slot and iteration):
+        ``attn_rows`` (rows per KV head the kernel computes on a live tile,
+        by its own rule) and ``attn_live_rows`` (G per live query);
+        ``attn_tiles`` (live table tiles swept, by the kernel's own
+        :func:`live_tiles`), ``attn_keys_fetched`` (their keys) and
+        ``attn_live_keys`` (keys the live rows attend: the slot's context
+        to its last query, from the first query's window)."""
         G = self.cfg.n_heads // self.cfg.n_kv_heads
+        lens = np.asarray(lens, np.int64)
         q_lens = np.asarray(q_lens, np.int64)
-        self.stats["attn_rows"] += int((rows_computed(G, width, q_lens) * reps).sum())
-        self.stats["attn_live_rows"] += int((G * q_lens * reps).sum())
+        _, tiles = live_tiles(lens, q_lens, **self._sweep, xp=np)
+        w = self._sweep["window"]
+        first_key = np.maximum(lens - (w - 1), 0) if w > 0 else 0
+        self.stats["attn_rows"] += int(rows_computed(G, width, q_lens).sum())
+        self.stats["attn_live_rows"] += int((G * q_lens).sum())
+        self.stats["attn_tiles"] += int(tiles.sum())
+        self.stats["attn_keys_fetched"] += int(tiles.sum()) * self._sweep["tile"]
+        self.stats["attn_live_keys"] += int(np.where(q_lens > 0, lens + q_lens - first_key, 0).sum())
 
     def run(self, requests=(), max_iterations: int = 100_000) -> dict:
         """Drive the stream to completion; returns {rid: generated tokens}

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.plan import int8_scale_lanes
-from repro.kernels.paged_attention.kernel import row_sizes, rows_computed
+from repro.kernels.paged_attention.kernel import row_sizes, rows_computed, sweep_schedule
 from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
@@ -30,9 +30,9 @@ def _scale_rows(s):
     return jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))
 
 
-def _mk_case(B, W, H, KH, D, bs, MB, kv_dtype, seed=0):
-    """Random pools + prefix-dense tables with ragged lens/q_lens; slot 0 is
-    empty (kinds 0, whole table on trash block 0)."""
+def _mk_case(B, W, H, KH, D, bs, MB, kv_dtype, seed=0, q_lens=None, lens=None):
+    """Random pools + prefix-dense tables with ragged lens/q_lens (or the
+    ones given); slot 0 is empty (kinds 0, whole table on trash block 0)."""
     key = jax.random.fold_in(KEY, seed)
     N = 1 + B * MB
     q = jax.random.normal(key, (B, W, H, D), jnp.float32)
@@ -45,8 +45,10 @@ def _mk_case(B, W, H, KH, D, bs, MB, kv_dtype, seed=0):
     else:
         entry = {"k": k.astype(kv_dtype), "v": v.astype(kv_dtype)}
     rng = np.random.default_rng(seed)
-    lens = rng.integers(0, MB * bs - W, B).astype(np.int32)
-    q_lens = rng.integers(1, W + 1, B).astype(np.int32)
+    drawn = rng.integers(0, MB * bs - W, B)
+    lens = np.asarray(drawn if lens is None else lens, np.int32)
+    drawn = rng.integers(1, W + 1, B)
+    q_lens = np.asarray(drawn if q_lens is None else q_lens, np.int32)
     table = np.zeros((B, MB), np.int32)
     for b in range(B):
         nb = -(-(int(lens[b]) + W) // bs)
@@ -75,15 +77,30 @@ CASES = [
     (4, 128, 4, 2, 32, 8, 32, "bfloat16", 24, 2, (0, 4, 65, 128)),
     # G = 16 on one KV head: sizes G and G*W = 128
     (3, 8, 16, 1, 32, 8, 8, "int8", 0, 2, (0, 1, 8)),
+    # multi-page tiles, with the prefetch crossing slot boundaries; each
+    # entry also gives lens, so contexts end where the case says:
+    # 8-page tiles (32 keys), an idle slot between two live ones, contexts
+    # ending mid-tile (slot 1 at key 37, slot 3 at key 72)
+    (4, 4, 4, 2, 32, 4, 32, "int8", 0, 8, (0, 1, 0, 3), (0, 37, 50, 70)),
+    (4, 4, 4, 2, 32, 4, 32, "bfloat16", 0, 8, (0, 1, 0, 3), (0, 37, 50, 70)),
+    # 16-page tiles (64 keys): a prefill slot after a decode slot, one
+    # context ending on a tile's first key (slot 3 at key 64)
+    (4, 4, 4, 2, 32, 4, 32, "int8", 0, 16, (0, 1, 4, 1), (0, 100, 20, 64)),
+    (4, 4, 4, 2, 32, 4, 32, "bfloat16", 0, 16, (0, 1, 4, 1), (0, 100, 20, 64)),
+    # windowed slots whose first live tile is not tile 0 (slot 1 from tile
+    # 3, slot 3 from tile 6), around an idle slot and a short prefill one
+    (5, 2, 4, 2, 32, 4, 16, "int8", 12, 2, (0, 2, 0, 1, 2), (0, 40, 5, 61, 3)),
+    (5, 2, 4, 2, 32, 4, 16, "bfloat16", 12, 2, (0, 2, 0, 1, 2), (0, 40, 5, 61, 3)),
+    # a window over 8-page tiles: slot 2 sweeps tiles 2-3 of 4, slot 1
+    # only its last
+    (3, 4, 4, 2, 32, 4, 32, "int8", 20, 8, (0, 4, 1), (0, 120, 100)),
 ]
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_kernel_matches_ref(case):
     B, W, H, KH, D, bs, MB, kv_dtype, window, ppt, *fixed = case
-    q, entry, table, lens, q_lens = _mk_case(B, W, H, KH, D, bs, MB, kv_dtype)
-    if fixed:
-        q_lens = jnp.asarray(fixed[0], jnp.int32)
+    q, entry, table, lens, q_lens = _mk_case(B, W, H, KH, D, bs, MB, kv_dtype, 0, *fixed)
     got = np.asarray(
         paged_attention(
             q, entry, table, lens, q_lens,
@@ -125,6 +142,25 @@ def test_row_sizes_rule(G, W, want):
     assert tuple(rows_computed(G, W, [0, 1, W])) == want
 
 
+@pytest.mark.parametrize(
+    "window, want",
+    [
+        # first live tile, live tiles, next live slot, ordinal of the first
+        (0, [[0, 0, 0, 0, 0], [0, 2, 0, 3, 1], [1, 3, 3, 4, 5], [0, 0, 2, 2, 5]]),
+        # the window drops slot 3's tile 0 (its first query sees keys 51-70)
+        (20, [[0, 0, 0, 1, 0], [0, 2, 0, 2, 1], [1, 3, 3, 4, 5], [0, 0, 2, 2, 4]]),
+    ],
+)
+def test_sweep_schedule(window, want):
+    """The kernel's sweep over slots idle, decode, idle, prefill, prefill:
+    live tiles only, each live slot pointing at the next one past the idle
+    slot between them, and tile ordinals that alternate the buffers."""
+    lens = jnp.asarray([0, 37, 50, 70, 5], jnp.int32)
+    q_lens = jnp.asarray([0, 1, 0, 3, 2], jnp.int32)
+    got = sweep_schedule(lens, q_lens, tile=32, n_tiles=4, window=window)
+    assert np.asarray(got).tolist() == want
+
+
 def test_tile_sweep_invariance():
     """Plan knob contract (paper C2 analog): pages_per_tile changes the VMEM
     schedule, never the numbers."""
@@ -136,7 +172,7 @@ def test_tile_sweep_invariance():
                 block_size=4, pages_per_tile=ppt,
             )
         )
-        for ppt in (1, 2, 4, 16)
+        for ppt in (1, 2, 4, 8, 16)
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-6, atol=1e-6)

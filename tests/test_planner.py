@@ -145,6 +145,27 @@ class TestDerivePlan:
 SERVABLE = [a for a in ALL_ARCHS if serve_feasible(get_config(a))[0]]
 
 
+class TestKernelTile:
+    """The paged kernel's table tile (``ServePlan.pages_per_tile``): derived
+    from the chip and the shapes, a divisor of the table width."""
+
+    def test_qwen3_on_v5e_fills_the_lanes(self):
+        sp = derive_serve_plan(get_config("qwen3-1.7b"), MESHES[2], TPU_V5E)
+        assert sp.max_blocks_per_seq % sp.pages_per_tile == 0
+        # a tile of keys fills a vreg's 128 lanes
+        assert sp.pages_per_tile * sp.block_size >= 128
+
+    @pytest.mark.parametrize("max_seq_len", [24, 64, 200])
+    def test_tiny_table_gets_a_divisor(self, max_seq_len):
+        cfg = get_config("smollm-135m").reduced()
+        sp = derive_serve_plan(
+            cfg, MESHES[2], max_seq_len=max_seq_len, decode_batch=4, block_size=8,
+            prefill_chunk=8,
+        )
+        assert 1 <= sp.pages_per_tile <= sp.max_blocks_per_seq
+        assert sp.max_blocks_per_seq % sp.pages_per_tile == 0
+
+
 class TestStepTokens:
     """The mixed step's token budget T (``ServePlan.step_tokens``): derived
     from B, W and gamma, never set by hand."""

@@ -289,6 +289,46 @@ def test_attn_row_counters_follow_the_packed_slab(key):
     assert_traces_bounded(engine.trace_counts)
 
 
+@pytest.mark.parametrize("arch", ["smollm-135m", "mixtral-8x7b"])
+def test_attn_tile_counters_follow_the_slot_lengths(key, arch):
+    """``attn_tiles``, ``attn_keys_fetched`` and ``attn_live_keys`` are the
+    kernel's live tiles, their keys and the keys the live rows attend,
+    counted slot by slot from the lengths and live rows each step
+    dispatched: three requests on four slots, so steps mix decode, prefill
+    and idle slots; mixtral's sliding window drops its slots' oldest
+    tiles."""
+    cfg, plan, serve, params = _setup(key, arch, rolled_steps=1, pages_per_tile=2)
+    T, n_tiles = 2 * serve.block_size, serve.max_blocks_per_seq // 2
+    window = cfg.sliding_window
+    engine = ServingEngine(params, cfg, plan, serve)
+    seen = []
+    step = engine._step
+    engine._step = lambda *a: (seen.append((np.asarray(a[4]), np.asarray(a[5]))), step(*a))[1]
+    rng = np.random.default_rng(5)
+    for i, n in enumerate((30, 9, 22)):
+        engine.submit(Request(f"t{i}", list(rng.integers(0, cfg.vocab_size, n)), 12))
+    kinds_seen = set()
+    while not engine.sched.idle:
+        before, n = dict(engine.stats), len(seen)
+        engine.step()
+        want = {"attn_tiles": 0, "attn_keys_fetched": 0, "attn_live_keys": 0}
+        for lens, kinds in seen[n:]:
+            for ln, q in zip(lens.tolist(), kinds.tolist()):
+                kinds_seen.add(min(q, 2))
+                if q == 0:
+                    continue
+                first = max(ln - window + 1, 0) if window else 0
+                tiles = sum(t * T <= ln + q - 1 and t * T + T - 1 >= first
+                            for t in range(n_tiles))
+                want["attn_tiles"] += tiles
+                want["attn_keys_fetched"] += tiles * T
+                want["attn_live_keys"] += ln + q - first
+        for k, v in want.items():
+            assert engine.stats[k] - before[k] == v, k
+    assert kinds_seen == {0, 1, 2}  # idle, decode and prefill slots
+    assert engine.stats["attn_keys_fetched"] >= engine.stats["attn_live_keys"] > 0
+
+
 def test_packed_step_keeps_parity_when_the_budget_binds(key):
     """More prompt rows than the step's budget (14 slots x ~25 rows against
     T = 128): the prompts are served over several steps, decode slots keep

@@ -73,7 +73,10 @@ def test_paged_attention_compiles_at_derived_tile(
     one_chip, compiled_kernels, kv_dtype, slab
 ):
     """The fused kernel at the derived pages_per_tile, for a width-1 slab
-    (every decode step and the rolled loop) and the plan's mixed slab."""
+    (every decode step and the rolled loop) and the plan's mixed slab:
+    Mosaic compiles it within the scoped VMEM it asks for, which stays
+    inside half the chip's VMEM, the planner's budget, with its margin."""
+    from repro.kernels.paged_attention.kernel import vmem_bytes, vmem_limit
     from repro.kernels.paged_attention.ops import paged_attention
 
     cfg = get_config(ARCH)
@@ -92,6 +95,13 @@ def test_paged_attention_compiles_at_derived_tile(
     else:
         entry = {"k": S((N, bs, KH, D), jnp.bfloat16), "v": S((N, bs, KH, D), jnp.bfloat16)}
     q = S((B, W, cfg.n_heads, D), jnp.float32)
+    shape = dict(
+        kv_dtype=kv_dtype, q_itemsize=4, kv_heads=KH, rows=cfg.n_heads // KH * W,
+        head_dim=D, block_size=bs, pages_per_tile=serve.pages_per_tile,
+    )
+    assert serve.pages_per_tile * bs >= 128
+    assert vmem_bytes(**shape) <= TPU_V5E.vmem_bytes // 2
+    assert vmem_limit(**shape) <= TPU_V5E.vmem_bytes * 5 // 8
     fn = jax.jit(
         lambda q, e, t, l, ql: paged_attention(
             q, e, t, l, ql, block_size=bs, pages_per_tile=serve.pages_per_tile
